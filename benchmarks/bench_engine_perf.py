@@ -57,9 +57,9 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.runtime_flags import enable_fast_cpu_runtime
+from repro.compile_cache import enable_compile_cache
 
-enable_fast_cpu_runtime()
+enable_compile_cache()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -247,10 +247,10 @@ def bench_kernels(*, rounds: int = 20, obs: Obs = NULL_OBS) -> dict:
     """
     from repro.kernels.attn.flash import flash_attention
     from repro.kernels.attn.ref import flash_attention_ref
-    from repro.kernels.dispatch import accelerator_backend
+    from repro.kernels.dispatch import pallas_interpret
     from repro.kernels.quant.ops import quant_dequant, quant_dequant_residual
 
-    interpret = not accelerator_backend()
+    interpret = pallas_interpret()
     out: dict[tuple[str, str], float] = {}
 
     def meas(case, variant, fn, *args):

@@ -13,9 +13,9 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.runtime_flags import enable_fast_cpu_runtime
+from repro.compile_cache import enable_compile_cache
 
-enable_fast_cpu_runtime()
+enable_compile_cache()
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402

@@ -16,9 +16,9 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.runtime_flags import enable_fast_cpu_runtime
+from repro.compile_cache import enable_compile_cache
 
-enable_fast_cpu_runtime()
+enable_compile_cache()
 
 import dataclasses  # noqa: E402
 

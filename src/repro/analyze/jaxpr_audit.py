@@ -224,7 +224,9 @@ def check_donation(jit_fn, args, donate_argnums, where: str) -> list[Finding]:
 # plan-level entry points
 # ---------------------------------------------------------------------------
 
-def _example_round_args(plan) -> tuple[tuple, dict]:
+def example_round_args(plan) -> tuple[tuple, dict]:
+    """``(args, audit)``: one round's positional arguments for the plan's
+    jitted round (from a fresh ``plan.init()``) and its ``_audit`` handle."""
     audit = getattr(plan._run, "_audit", None)
     if audit is None:
         raise ValueError(
@@ -244,7 +246,7 @@ def _example_round_args(plan) -> tuple[tuple, dict]:
 
 def audit_plan(plan, *, const_budget_bytes: int = 1 << 20) -> Report:
     """All six structural checks over ``plan``'s compiled round."""
-    args, audit = _example_round_args(plan)
+    args, audit = example_round_args(plan)
     jit_fn = audit["jit_fn"]
     where = f"round[{plan.spec.describe()}]"
     report = Report(checked=[where])

@@ -36,8 +36,8 @@ from ..core.trajectory import TourPlan, plan_tour
 from ..data.partition import (partition_dirichlet, partition_iid,
                               partition_non_iid, population_partition_count)
 from ..data.synthetic import SyntheticPestImages, synthetic_tokens
-from ..fleet.engine import (make_fleet_fl_round, make_fleet_sl_round,
-                            server_mesh_sizes, shard_server_state,
+from ..fleet.engine import (fleet_sl_state_shardings, make_fleet_fl_round,
+                            make_fleet_sl_round, server_mesh_sizes,
                             validate_fleet_mesh)
 from ..launch.mesh import make_fleet_mesh, single_device_fleet_mesh
 from ..fleet.hetero import (HeteroFleet, assign_cuts_cnn, cnn_split_program,
@@ -659,14 +659,16 @@ def _resolve_mesh(spec: ExperimentSpec, mesh):
     f, t = server_mesh_sizes(mesh)
     if (eng.client_axis == "shard_map" and f * t > 1
             and jax.default_backend() == "cpu"):
-        # this repo's pinned XLA:CPU partitioner aborts (hard, not an
-        # exception) on fsdp/tp-sharded operands entering the manual
-        # body's scan — see fleet.engine and ROADMAP; the vmap engine
-        # runs the full 2D layout on every backend
+        # XLA:CPU's SPMD partitioner (jax 0.9) still refuses this layout:
+        # it fails the compile with RET_CHECK "IsManualSubgroup()
+        # Cross-partition allreduce must be in (partial) manual
+        # partitioning mode". The TPU compiler partitions it. The vmap
+        # engine runs the full 2D layout on every backend.
         raise ValueError(
             "client_axis='shard_map' with a >1 server_mesh is gated off "
-            "the CPU backend (XLA:CPU partitioner abort in the pinned "
-            "toolchain); use client_axis='vmap' for the 2D layout on CPU")
+            "the CPU backend (XLA:CPU's SPMD partitioner refuses the "
+            "partial-manual all-reduce); use client_axis='vmap' for the "
+            "2D layout on CPU")
     return mesh
 
 
@@ -727,7 +729,8 @@ def _compile_plan(spec: ExperimentSpec, *, mesh, data, obs: Obs) -> Plan:
     use_pallas_link, interpret_link = resolve_link_kernel(
         spec.engine.link_kernel)
     link = FleetLink(config=spec.link_policy.config(),
-                     use_pallas=use_pallas_link, interpret=interpret_link)
+                     use_pallas=use_pallas_link, interpret=interpret_link,
+                     mesh=mesh)
     scn = spec.scenario
 
     # ---- mission: placement, tour/timeline, round budget -----------------
@@ -792,12 +795,12 @@ def _compile_plan(spec: ExperimentSpec, *, mesh, data, obs: Obs) -> Plan:
             sample_bx = jnp.asarray(x_train[:spec.batch_size])
             sample_by = jnp.asarray(y_train[:spec.batch_size])
         with obs.span("compile/flops"):
-            # FLOPs are counted on the tap-free step twin so the hoisted
-            # energy/link constants — and every non-metrics record field —
-            # stay bitwise identical with the metrics bus on
+            # FLOPs are counted on the step's tap-free, recompute-free twin
+            # so the hoisted energy/link constants — and every non-metrics
+            # record field — stay bitwise identical with the metrics bus on
             fl_client, fl_server, smashed_sd = count_split_step_flops(
-                dataclasses.replace(prog.step, taps=()), prog.params_c0,
-                prog.params_s0, sample_bx, sample_by)
+                prog.cost_step, prog.params_c0, prog.params_s0, sample_bx,
+                sample_by)
         flops[k] = (fl_client, fl_server, smashed_sd)
         for cid in range(n):
             lc = client_link(cid)
@@ -1113,25 +1116,30 @@ def _compile_sl_fleet(spec, mesh, stages, params0, cut_of_client, link,
                                      client_tier="shared" if shared
                                      else "stacked",
                                      server_pspecs=sps_specs, taps=taps)
-        round_fn = jax.jit(raw_fn, donate_argnums=(0, 1, 2, 3))
 
-        def init_state():
+        def fresh_state():
             if shared:
                 state = (cp0, sp, opt_c.init(cp0), opt_s.init(sp))
             else:
                 state = (stack_replicas(cp0, n), sp,
                          init_stacked(opt_c, cp0, n), opt_s.init(sp))
-            state = jax.tree_util.tree_map(jnp.copy, state)
-            if sps_specs is not None:
-                from jax.sharding import PartitionSpec as P
-                from ..optim.optimizers import OptState
-                pc, ps, oc, os_ = state
-                ps = shard_server_state(ps, mesh, sps_specs)
-                os_ = shard_server_state(
-                    os_, mesh, OptState(step=P(), mu=sps_specs,
-                                        nu=sps_specs))
-                state = (pc, ps, oc, os_)
-            return state
+            return jax.tree_util.tree_map(jnp.copy, state)
+
+        if mesh is None:
+            round_fn = jax.jit(raw_fn, donate_argnums=(0, 1, 2, 3))
+            init_state = fresh_state
+        else:
+            # the round returns its state where init_state places it, so
+            # every round of a run (the first included) is one program
+            shardings = fleet_sl_state_shardings(
+                jax.eval_shape(fresh_state), mesh, shared=shared,
+                server_pspecs=sps_specs)
+            round_fn = jax.jit(
+                raw_fn, donate_argnums=(0, 1, 2, 3),
+                out_shardings=shardings + (None,) * (2 if taps else 1))
+
+            def init_state():
+                return jax.device_put(fresh_state(), shardings)
 
         def global_prefix(client_stack):
             return (client_stack if shared

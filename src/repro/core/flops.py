@@ -2,9 +2,13 @@
 
 Two sources, tried in order:
 
-1. **XLA** — ``compiled.cost_analysis()``. Its return type varies across jax
-   versions (dict, or a per-device *list* of dicts on 0.4.3x); ``compiled_cost``
-   normalizes both. On some backends it is missing or reports 0.
+1. **XLA** — ``compiled.cost_analysis()`` of the program compiled for the
+   host CPU, whatever the default backend: the count prices the paper's
+   hardware (Jetson clients, an A5000 server), so it is the same number on
+   every backend, and it never has to fit an accelerator's memory. Pallas
+   kernels in it run in interpret mode, as they do on the CPU. On some
+   backends the count is missing or 0; a program that fails to compile
+   raises.
 2. **Analytic jaxpr walk** — ``jaxpr_flops`` traverses the traced jaxpr and
    counts matmul/conv FLOPs exactly (2*M*N*K style) and one FLOP per output
    element for the remaining arithmetic primitives, recursing through
@@ -21,6 +25,7 @@ import math
 from typing import Optional
 
 import jax
+from jax.sharding import SingleDeviceSharding
 
 # Primitives that move/alias data without arithmetic — zero FLOPs.
 _FREE_PRIMS = frozenset({
@@ -94,26 +99,24 @@ def jaxpr_flops(fn, *args) -> float:
 
 
 def compiled_cost(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Returns an (possibly empty) dict: newer jax returns a dict directly,
-    0.4.3x returns a one-element list of per-device dicts.
-    """
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return dict(cost) if cost else {}
+    """``compiled.cost_analysis()`` as a (possibly empty) dict."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def xla_flops(fn, *args) -> Optional[float]:
-    """XLA-counted FLOPs of one invocation, or None when unavailable."""
-    try:
-        compiled = jax.jit(fn).lower(*args).compile()
-    except Exception:
-        return None
+    """XLA:CPU-counted FLOPs of one invocation, or None when no count is
+    reported. Only the shapes of ``args`` are used. A failed compile
+    raises."""
+    host = jax.local_devices(backend="cpu")[0]
+    on_host = SingleDeviceSharding(host)
+
+    def struct(a):
+        aval = jax.typeof(a)
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype, sharding=on_host,
+                                    weak_type=aval.weak_type)
+
+    with jax.default_device(host):    # kernels/dispatch: interpret Pallas
+        compiled = jax.jit(fn).lower(*jax.tree.map(struct, args)).compile()
     flops = float(compiled_cost(compiled).get("flops", -1.0))
     return flops if flops > 0.0 else None
 

@@ -40,9 +40,10 @@ Layout
                 tradeoff across fleet sizes, cuts and link modes.
 """
 from .engine import (CLIENT_AXES, FLEET_EQUIV_ATOL, fleet_sharding,
-                     make_fleet_fl_round, make_fleet_sl_round,
-                     server_mesh_sizes, shard_client_stack,
-                     shard_server_state, validate_fleet_mesh)
+                     fleet_sl_state_shardings, make_fleet_fl_round,
+                     make_fleet_sl_round, server_mesh_sizes,
+                     shard_client_stack, shard_fleet_sl_state,
+                     validate_fleet_mesh)
 from .hetero import (CutBucket, HeteroFleet, SplitProgram,
                      arch_split_program, assign_cuts_cnn,
                      assign_cuts_transformer, bucket_by_cut,

@@ -17,17 +17,19 @@ one of two layouts (``client_axis=``):
     ``lax.pmean``. The collective schedule is pinned in the program — the
     prerequisite for multi-host meshes, where GSPMD inference can differ
     per host. The non-``data`` mesh axes (``fsdp``, ``tp``) stay
-    GSPMD-``auto``.
+    automatic (outside the map's ``axis_names``).
 
 The 2D (clients x server-model) layout: ``launch.mesh.make_fleet_mesh``
 builds the ``('data','fsdp','tp')`` mesh, ``launch.steps
 .fleet_server_pspecs`` derives the server suffix's tier specs (the same
 DESIGN.md §3 rule ``build_step`` applies), and ``server_pspecs=`` wires
 them into the SL round — server params/optimizer state shard fsdp x tp
-(place live state with ``shard_server_state``) while the client stack
-shards over ``data``. The combination with ``shard_map`` is gated to
-fsdp = tp = 1 on this repo's XLA:CPU toolchain (partitioner abort, see
-``make_fleet_sl_round``); the vmap engine runs the full 2D layout today.
+while the client stack shards over ``data`` (``fleet_sl_state_shardings``
+is that layout; place live state with ``shard_fleet_sl_state``). The
+combination with ``shard_map`` compiles for the TPU but is gated to fsdp =
+tp = 1 on the CPU backend, whose SPMD partitioner refuses it (see
+``make_fleet_sl_round``); the vmap engine runs the full 2D layout on every
+backend.
 
 Round semantics per engine:
 
@@ -59,7 +61,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.fedavg import (fedavg_mean, fedavg_mean_masked, fedavg_pmean,
@@ -68,7 +69,7 @@ from ..core.fedavg import (fedavg_mean, fedavg_mean_masked, fedavg_pmean,
                            fedavg_stack_masked)
 from ..core.split import SplitStep, make_fl_round
 from ..obs.metrics import tree_nonfinite, tree_norm
-from ..optim.optimizers import apply_updates
+from ..optim.optimizers import OptState, apply_updates
 
 # Documented loosened tolerance for vmapped/sharded vs sequential rounds
 # (see module docstring; tests and benches assert against this bound).
@@ -131,11 +132,11 @@ def _resolve_shard_map_mesh(mesh):
 
 def _client_shard_map(body, mesh, in_specs, out_specs):
     """shard_map manual over ``data`` only; every other mesh axis (fsdp/tp)
-    is left to GSPMD (``auto``) so in-map sharding constraints can lay out
-    the server suffix."""
-    auto = frozenset(mesh.axis_names) - {CLIENT_AXIS_NAME}
-    return shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, auto=auto)
+    stays automatic (left out of ``axis_names``) so in-map sharding
+    constraints can lay out the server suffix."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={CLIENT_AXIS_NAME},
+                         check_vma=False)
 
 
 def server_mesh_sizes(mesh) -> tuple[int, int]:
@@ -147,16 +148,40 @@ def server_mesh_sizes(mesh) -> tuple[int, int]:
     return sizes.get("fsdp", 1), sizes.get("tp", 1)
 
 
-def shard_server_state(tree, mesh, server_pspecs):
-    """Host-side placement of the server suffix (params, or a matching
-    state tree such as ``OptState(step=P(), mu=specs, nu=specs)``) onto the
-    fleet mesh's ``fsdp`` x ``tp`` server sub-mesh — the counterpart of
-    ``shard_client_stack`` for the 2D (clients x server-model) layout."""
-    if mesh is None or server_pspecs is None:
-        return tree
-    return jax.tree_util.tree_map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-        tree, server_pspecs)
+def fleet_sl_state_shardings(state, mesh, *, shared: bool = False,
+                             server_pspecs=None):
+    """Shardings of a fleet SL round's state ``(client params, server
+    params, client opt state, server opt state)`` on ``mesh`` — the one
+    source of the 2D (clients x server-model) layout. The per-client stacks
+    shard over ``data`` (replicated for the shared client tier); the server
+    suffix and its optimizer moments follow ``server_pspecs``
+    (``launch.steps.fleet_server_pspecs``), replicated when None.
+    ``shard_fleet_sl_state`` places live state by it, and a round jitted
+    with it as ``out_shardings`` returns its state where it found it, so
+    every round of a run is one program. ``state`` may be abstract
+    (``jax.eval_shape``)."""
+    pc, ps, oc, os_ = state
+    client = NamedSharding(mesh, P() if shared else P(CLIENT_AXIS_NAME))
+    if server_pspecs is None:
+        server = jax.tree_util.tree_map(
+            lambda _: NamedSharding(mesh, P()), ps)
+    else:
+        server = jax.tree_util.tree_map(
+            lambda spec: NamedSharding(mesh, spec), server_pspecs,
+            is_leaf=lambda x: isinstance(x, P))
+    per_client = lambda tree: jax.tree_util.tree_map(lambda _: client, tree)
+    moments = lambda m: None if m is None else server
+    return (per_client(pc), server, per_client(oc),
+            OptState(step=NamedSharding(mesh, P()), mu=moments(os_.mu),
+                     nu=moments(os_.nu)))
+
+
+def shard_fleet_sl_state(state, mesh, **kw):
+    """Host-side placement of a fleet SL state per
+    ``fleet_sl_state_shardings`` (same keywords)."""
+    if mesh is None:
+        return state
+    return jax.device_put(state, fleet_sl_state_shardings(state, mesh, **kw))
 
 
 def _server_constrainer(mesh, server_pspecs) -> Optional[Callable]:
@@ -165,8 +190,8 @@ def _server_constrainer(mesh, server_pspecs) -> Optional[Callable]:
     round's scan carry. Trivial spec trees (every dim replicated — fsdp =
     tp = 1) collapse to None so the shard_map body stays constraint-free
     on 1D meshes. (Inside a manual-over-``data`` body the constraint must
-    also stay OUTSIDE the scan: this toolchain's SPMD partitioner aborts
-    on auto-axis resharding inside a while-loop of a manual computation —
+    also stay OUTSIDE the scan: XLA:CPU's SPMD partitioner refuses
+    auto-axis resharding inside a while-loop of a manual computation —
     see ``api.plan`` for the backend gate.)"""
     if mesh is None or server_pspecs is None:
         return None
@@ -294,14 +319,13 @@ def make_fleet_sl_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
     ``server_pspecs`` (a PartitionSpec tree from
     ``launch.steps.fleet_server_pspecs``) constrains the server suffix over
     the mesh's ``fsdp`` x ``tp`` axes at round entry, giving the 2D
-    (clients x server-model) layout; ``shard_server_state`` places the live
-    state to match. Fully supported under ``client_axis='vmap'`` (pure
-    GSPMD). Under ``shard_map`` those axes are GSPMD-``auto`` and the
-    combination is the intended multi-host layout, but this repo's pinned
-    XLA:CPU toolchain aborts on fsdp/tp-sharded operands entering the
-    manual body's scan — ``api.plan`` gates the CPU backend to fsdp = tp =
-    1 for shard_map (see ROADMAP, re-test when the toolchain moves past
-    jax 0.5).
+    (clients x server-model) layout; ``shard_fleet_sl_state`` places the
+    live state to match. Fully supported under ``client_axis='vmap'`` (pure
+    GSPMD). Under ``shard_map`` those axes stay automatic and the
+    combination is the intended multi-host layout. The TPU compiler
+    partitions it; XLA:CPU's SPMD partitioner refuses the partial-manual
+    all-reduce (RET_CHECK ``IsManualSubgroup``), so ``api.plan`` gates the
+    CPU backend to fsdp = tp = 1 for shard_map.
 
     Signature matches ``make_multi_client_round``:
     ``f(params_c_stack, params_s, oc_stack, os_, batches)`` with ``batches``
@@ -363,6 +387,9 @@ def make_fleet_sl_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
         axis = None
         constrain_mesh = mesh
     constrain_server = _server_constrainer(mesh, server_pspecs)
+    # vmap's client axis is the sharded `data` axis: per-device kernels
+    # (kernels.dispatch.per_device) inside the step keep it sharded
+    spmd_axis = CLIENT_AXIS_NAME if constrain_mesh is not None else None
 
     def allreduce_sum(x):
         return jax.lax.psum(x, axis) if axis is not None else x
@@ -398,8 +425,8 @@ def make_fleet_sl_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
         def round_body(carry, batch_r):
             params_c_stack, oc_stack, params_s, os_ = carry
             grads_out = jax.vmap(
-                per_client_grads, in_axes=(0, 0, None))(
-                    params_c_stack, batch_r, params_s)
+                per_client_grads, in_axes=(0, 0, None),
+                spmd_axis_name=spmd_axis)(params_c_stack, batch_r, params_s)
             if taps:
                 losses, aux_t, g_c_stack, g_s_stack = grads_out
             else:
@@ -522,8 +549,8 @@ def make_fleet_sl_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
         def round_body(carry, batch_r):
             params_c, oc, params_s, os_ = carry
             grads_out = jax.vmap(
-                per_client_grads, in_axes=(0, None, None))(
-                    batch_r, params_c, params_s)
+                per_client_grads, in_axes=(0, None, None),
+                spmd_axis_name=spmd_axis)(batch_r, params_c, params_s)
             if taps:
                 losses, aux_t, g_c_stack, g_s_stack = grads_out
             else:

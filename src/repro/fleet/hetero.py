@@ -195,8 +195,16 @@ class LMSplitProgram:
     cross the link, the split-learning privacy floor), the server tier owns
     its block slice plus the output head, and ``server_logits`` exposes the
     full forward for held-out evaluation.
+
+    ``step`` recomputes each block in its backward pass rather than saving
+    its activations (``jax.checkpoint``): at SmolLM-135M widths and seq
+    2048 the activations the 30 blocks would save do not fit one 16 GB
+    v5e. ``cost_step`` is the same program without that recompute and
+    without taps: the work the energy bill prices, since the recompute is
+    a device-memory choice, not work of the paper's client hardware.
     """
     step: SplitStep
+    cost_step: SplitStep
     params_c0: object             # {"embed": (V, d), "blocks": client stack}
     params_s0: object             # {"blocks": server stack, "head": (d, V)}
     cut_index: int
@@ -232,27 +240,32 @@ def lm_split_program(cfg, key, k: int, *,
     block_apply = transformer_block_apply(cfg, window=window,
                                           attn_impl=attn_impl)
 
-    def run_blocks(stack, h):
+    def run_blocks(stack, h, remat=False):
         def body(h, blk):
             return block_apply(blk, h), None
-        h, _ = jax.lax.scan(body, h, stack)
+        h, _ = jax.lax.scan(jax.checkpoint(body) if remat else body, h,
+                            stack)
         return h
-
-    def client_fwd(pc, tokens):
-        return run_blocks(pc["blocks"], pc["embed"][tokens])
 
     def server_logits(ps, smashed):
         return run_blocks(ps["blocks"], smashed) @ ps["head"]
 
-    def server_loss(ps, smashed, targets):
-        logits = server_logits(ps, smashed)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return jnp.mean(nll), {}
+    def make_step(remat, **kw):
+        def client_fwd(pc, tokens):
+            return run_blocks(pc["blocks"], pc["embed"][tokens], remat)
 
-    step = SplitStep(client_fwd=client_fwd, server_loss=server_loss,
-                     link_constraint=link_boundary, taps=taps)
-    return LMSplitProgram(step=step,
+        def server_loss(ps, smashed, targets):
+            logits = run_blocks(ps["blocks"], smashed, remat) @ ps["head"]
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+            return jnp.mean(nll), {}
+
+        return SplitStep(client_fwd=client_fwd, server_loss=server_loss,
+                         **kw)
+
+    return LMSplitProgram(step=make_step(True, link_constraint=link_boundary,
+                                         taps=taps),
+                          cost_step=make_step(False),
                           params_c0={"embed": embed, "blocks": blocks_c},
                           params_s0={"blocks": blocks_s, "head": head},
                           cut_index=k, server_logits=server_logits)

@@ -20,7 +20,7 @@ through one autodiff program (and vmaps over the fleet's client axis).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 from ..core.link import LinkConfig
 from ..core.split import SplitStep
@@ -30,13 +30,15 @@ from ..core.split import SplitStep
 class FleetLink:
     """One edge<->server link: config + the kernel path of its compressor.
 
-    ``use_pallas``/``interpret`` select the Pallas TPU kernel vs its jnp
-    oracle (the oracle is the right default on CPU containers; interpret
-    mode runs the Pallas kernel off-TPU for parity tests).
+    ``use_pallas`` selects the Pallas kernel vs its jnp reference;
+    ``interpret`` (set from ``kernels.dispatch.resolve_link_kernel``) runs
+    that kernel in interpret mode off-accelerator. ``mesh`` is the fleet
+    mesh the kernel runs per device on.
     """
     config: LinkConfig = LinkConfig()
     use_pallas: bool = False
-    interpret: bool = True
+    interpret: bool = False
+    mesh: Optional[Any] = None
 
     @property
     def compressed(self) -> bool:
@@ -48,7 +50,7 @@ class FleetLink:
             return None
         from ..kernels.quant.ops import make_link_compress
         return make_link_compress(use_pallas=self.use_pallas,
-                                  interpret=self.interpret)
+                                  interpret=self.interpret, mesh=self.mesh)
 
     def attach(self, step: SplitStep) -> SplitStep:
         """Opt the split step into this link (compose with any existing

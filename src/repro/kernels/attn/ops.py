@@ -1,7 +1,8 @@
 """jit'd wrapper choosing Pallas (TPU) or the jnp fallback, in model layout.
 
 Models use (B,S,H,D); the kernel uses (B,H,S,D). GQA KV heads are repeated
-here. On CPU containers the Pallas path runs in interpret mode (tests); the
+here. ``interpret`` defaults to the compiled kernel; off-accelerator the
+caller passes ``kernels.dispatch.pallas_interpret()``. The
 default model path uses the chunked-jnp implementation in
 ``repro.models.attention`` which XLA fuses natively.
 """
@@ -19,7 +20,7 @@ from .ref import flash_attention_ref
 
 @partial(jax.jit, static_argnames=("causal", "window", "use_pallas", "interpret"))
 def attention(q, k, v, *, causal=True, window=None, use_pallas=False,
-              interpret=True):
+              interpret=False):
     """q (B,S,H,D); k,v (B,S,Kh,D) -> (B,S,H,D)."""
     h = q.shape[2]
     k = gqa_repeat(k, h // k.shape[2]).transpose(0, 2, 1, 3)

@@ -3,8 +3,10 @@
 The spec layer names *intents* (``ModelSpec.attn_impl``,
 ``EngineSpec.link_kernel``); this module resolves them to concrete kernel
 paths at lowering time. Resolution is the ONLY place backend sniffing
-happens — everything below takes explicit ``use_pallas``/``interpret``
-flags:
+happens, and the only place that decides ``interpret``: every kernel
+wrapper below takes explicit ``use_pallas``/``interpret`` flags and
+defaults to the compiled kernel (``interpret=False``), so interpret mode
+runs only where this module, a caller or a test asks for it:
 
 - ``"auto"``   -> Pallas on an accelerator backend (TPU/GPU), the XLA
   reference path on CPU (where Pallas only runs in interpret mode and is
@@ -19,15 +21,56 @@ flags:
 """
 from __future__ import annotations
 
+from typing import Callable
+
 import jax
+from jax.sharding import AxisType, PartitionSpec as P
 
 ATTN_IMPLS = ("auto", "xla", "pallas", "ref")
 LINK_KERNELS = ("auto", "xla", "fused")
 
 
 def accelerator_backend() -> bool:
-    """True when the default JAX backend compiles Pallas natively."""
-    return jax.default_backend() in ("tpu", "gpu")
+    """True when the code being traced compiles Pallas natively: on the
+    platform of ``jax.default_device`` where one is set (the FLOP count
+    compiles for the host this way), else on the default backend."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        platform = jax.default_backend()
+    else:
+        platform = dev if isinstance(dev, str) else dev.platform
+    return platform in ("tpu", "gpu")
+
+
+def pallas_interpret() -> bool:
+    """Whether a Pallas kernel must run in interpret mode on this backend
+    (true only off-accelerator, where it is a correctness oracle)."""
+    return not accelerator_backend()
+
+
+def per_device(fn: Callable, mesh=None) -> Callable:
+    """Wrap a one-operand Pallas kernel call so each device runs it on its
+    own block: XLA's partitioner cannot split a Mosaic kernel across a
+    mesh. Inside a ``shard_map`` the wrapper maps over the context mesh's
+    axes that are still automatic; outside one it maps over every axis of
+    ``mesh``, with the operand replicated, so a caller that ``vmap``s over
+    a sharded batch axis passes ``spmd_axis_name`` to keep that axis
+    sharded. With neither a context mesh nor ``mesh``, ``fn`` runs as is.
+    """
+    def wrapped(x):
+        ctx = jax.sharding.get_abstract_mesh()
+        if not ctx.empty:
+            auto = {a for a, t in zip(ctx.axis_names, ctx.axis_types)
+                    if t == AxisType.Auto}
+            if not auto:
+                return fn(x)
+            return jax.shard_map(fn, in_specs=P(), out_specs=P(),
+                                 axis_names=auto, check_vma=False)(x)
+        if mesh is None:
+            return fn(x)
+        return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(x)
+    return wrapped
 
 
 def resolve_attn_impl(impl: str) -> str:
@@ -46,4 +89,4 @@ def resolve_link_kernel(kind: str) -> tuple[bool, bool]:
             f"link_kernel must be one of {LINK_KERNELS}, got {kind!r}")
     if kind == "auto":
         kind = "fused" if accelerator_backend() else "xla"
-    return kind == "fused", not accelerator_backend()
+    return kind == "fused", pallas_interpret()

@@ -12,13 +12,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..dispatch import per_device
 from .int8 import quant_dequant_int8
 from .ref import dequantize_int8_ref, quantize_int8_ref
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def quant_dequant(x: jax.Array, *, use_pallas: bool = False,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     if use_pallas:
@@ -34,7 +35,7 @@ def quant_dequant(x: jax.Array, *, use_pallas: bool = False,
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def quant_dequant_residual(x: jax.Array, residual: jax.Array, *,
                            use_pallas: bool = False,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """Server-side fused epilogue: ``dequant(quant(x)) + residual`` in one
     kernel — the serve tier adds the incoming smashed activations onto the
     server residual stream without materializing the dequantized tensor."""
@@ -51,21 +52,29 @@ def quant_dequant_residual(x: jax.Array, residual: jax.Array, *,
     return y.reshape(shape)
 
 
-def make_link_compress(*, use_pallas: bool = False, interpret: bool = True):
+def make_link_compress(*, use_pallas: bool = False, interpret: bool = False,
+                       mesh=None):
     """Build a straight-through int8 link compressor bound to one kernel path.
 
     The fleet link layer (``repro.fleet.link``) uses this to wire the Pallas
-    kernel (or its jnp oracle on CPU containers) into ``SplitStep`` as an
-    opt-in compressed boundary; the returned callable is vmap-able, so the
-    sharded fleet engine can batch it over the client axis.
+    kernel (or its jnp reference) into ``SplitStep`` as an opt-in compressed
+    boundary, with ``interpret`` from ``kernels.dispatch``; the returned
+    callable is vmap-able, so the sharded fleet engine can batch it over
+    the client axis. On a multi-device fleet ``mesh`` the Pallas kernel runs
+    per device (``kernels.dispatch.per_device``).
     """
+    def kernel(x):
+        return quant_dequant(x, use_pallas=use_pallas, interpret=interpret)
+
+    if use_pallas:
+        kernel = per_device(kernel, mesh)
 
     @jax.custom_vjp
     def compress(x: jax.Array) -> jax.Array:
-        return quant_dequant(x, use_pallas=use_pallas, interpret=interpret)
+        return kernel(x)
 
     def _fwd(x):
-        return compress(x), None
+        return kernel(x), None
 
     def _bwd(_, g):
         return (g,)   # straight-through
@@ -74,5 +83,5 @@ def make_link_compress(*, use_pallas: bool = False, interpret: bool = True):
     return compress
 
 
-# default compressor: jnp oracle path (runs everywhere, incl. CPU containers)
+# default compressor: the jnp reference path (runs on every backend)
 link_compress = make_link_compress()

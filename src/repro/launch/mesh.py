@@ -73,10 +73,6 @@ def single_device_fleet_mesh():
 
 
 def abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free AbstractMesh across jax versions: newer jax takes
-    ``(sizes, names)``; 0.4.3x takes one tuple of (name, size) pairs."""
+    """Device-free ``AbstractMesh`` of the given sizes and axis names."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))

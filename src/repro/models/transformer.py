@@ -257,10 +257,10 @@ def _attn_block(cfg, p, x, positions, *, window, causal=True, kv=None,
         out = chunked_causal_attention(q, k, v, window=window, causal=causal)
     else:
         from ..kernels.attn.ops import attention
-        from ..kernels.dispatch import accelerator_backend
+        from ..kernels.dispatch import pallas_interpret
         out = attention(q, k, v, causal=causal, window=window,
                         use_pallas=(attn_impl == "pallas"),
-                        interpret=not accelerator_backend())
+                        interpret=pallas_interpret())
     out = nn.linear_apply(attn_p["wo"], out.reshape(b, s, cfg.n_heads * cfg.hd))
     return x + out
 

@@ -1,9 +1,9 @@
 """Run-wide gauges: recompiles, pytree/engine-state bytes, host RSS.
 
 Everything here is zero-dependency and survives being unavailable: the
-recompile counter hooks jax's internal monitoring events (present on the
-pinned jax 0.4/0.5 line) but degrades to ``available=False`` if the
-private module moves; RSS reads ``/proc`` and falls back to ``resource``.
+recompile counter hooks jax's internal monitoring events but degrades to
+``available=False`` if the private module moves; RSS reads ``/proc`` and
+falls back to ``resource``.
 
 The recompile counter answers the question ``RoundRecord`` can't: did XLA
 silently recompile a round mid-run (a shape change, a new donation
@@ -84,12 +84,8 @@ class RecompileCounter:
     def uninstall(self) -> None:
         if not self._installed:
             return
-        try:
-            from jax._src import monitoring
-            monitoring._unregister_event_duration_listener_by_callback(
-                self._listen)
-        except Exception:
-            pass
+        from jax._src import monitoring
+        monitoring.unregister_event_duration_listener(self._listen)
         self._installed = False
         self.available = False
 

@@ -3,9 +3,9 @@
 ``ObsConfig(profile_rounds=(2, 4))`` arms a capture that starts when round
 2 begins and stops after round 4 ends; the trace lands in
 ``<run_dir>/profile/`` (open with TensorBoard's profile plugin or
-Perfetto). Capture failures never fail the run — the status lands in the
-manifest instead (``"unavailable: ..."``), because the profiler's native
-hooks are the one piece of this subsystem the pinned toolchain could drop.
+Perfetto). The status lands in the manifest. A capture the caller asked for
+that fails to start or stop raises: a run that was meant to be traced never
+ends as if it had been.
 """
 from __future__ import annotations
 
@@ -29,14 +29,11 @@ class ProfilerCapture:
         if (self.rounds is None or self.active
                 or round_index != self.rounds[0]):
             return
-        try:
-            import jax.profiler
-            os.makedirs(self.out_dir, exist_ok=True)
-            jax.profiler.start_trace(self.out_dir)
-            self.active = True
-            self.status = f"tracing rounds {self.rounds[0]}..{self.rounds[1]}"
-        except Exception as e:                      # never fail the run
-            self.status = f"unavailable: {type(e).__name__}: {e}"
+        import jax.profiler
+        os.makedirs(self.out_dir, exist_ok=True)
+        jax.profiler.start_trace(self.out_dir)
+        self.active = True
+        self.status = f"tracing rounds {self.rounds[0]}..{self.rounds[1]}"
 
     def round_finished(self, round_index: int) -> None:
         if self.active and round_index >= self.rounds[1]:
@@ -48,10 +45,7 @@ class ProfilerCapture:
             self._stop()
 
     def _stop(self) -> None:
-        try:
-            import jax.profiler
-            jax.profiler.stop_trace()
-            self.status = f"captured -> {self.out_dir}"
-        except Exception as e:
-            self.status = f"stop failed: {type(e).__name__}: {e}"
+        import jax.profiler
         self.active = False
+        jax.profiler.stop_trace()
+        self.status = f"captured -> {self.out_dir}"

@@ -1,13 +1,7 @@
 import os
 import sys
 
-# the legacy XLA:CPU runtime parallelizes grad kernels inside scan bodies
-# (the scanned multi-client engine's hot path) — must be set before jax
-# initializes its backend. See repro.runtime_flags.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-from repro.runtime_flags import enable_fast_cpu_runtime  # noqa: E402
-
-enable_fast_cpu_runtime()
 
 import jax  # noqa: E402
 import pytest  # noqa: E402

@@ -104,8 +104,7 @@ def test_callback_detected_through_scan():
 
 
 def test_f64_promotion_detected():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x * np.float64(2.0))(np.float64(1.0))
     findings = check_f64(closed, "bad")
@@ -116,15 +115,14 @@ def test_f64_promotion_detected():
 
 
 def test_collective_axis_checked_against_mesh():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import single_device_fleet_mesh
 
     mesh = single_device_fleet_mesh()
 
     def fn(x):
-        return shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
-                         in_specs=P("data"), out_specs=P())(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
+                             in_specs=P("data"), out_specs=P())(x)
 
     closed = jax.make_jaxpr(fn)(jnp.ones(4))
     # the psum really names the 'data' axis

@@ -141,6 +141,28 @@ def test_symmetric_flop_accounting(tiny_setup):
     assert 0.5 * full < client_fl + server_fl < 1.5 * full
 
 
+def test_xla_flops_counts_on_host_from_shapes():
+    """The bill's FLOP count compiles for the host CPU from the arguments'
+    shapes alone, whatever device is the default: on an accelerator host a
+    Pallas kernel in the program is counted in interpret mode."""
+    from repro.core.flops import xla_flops
+    from repro.kernels.dispatch import pallas_interpret
+    from repro.kernels.quant.ops import quant_dequant
+
+    def fn(x, scale):
+        y = quant_dequant(x * scale, use_pallas=True,
+                          interpret=pallas_interpret())
+        return jnp.sum(y @ x.T)
+
+    x = jnp.ones((64, 128), jnp.float32)
+    counted = xla_flops(fn, x, 2.0)
+    assert counted > 2 * 64 * 64 * 128
+    assert xla_flops(fn, jax.ShapeDtypeStruct(x.shape, x.dtype), 2.0) \
+        == counted
+    with jax.default_device("tpu"):          # as on a TPU host
+        assert xla_flops(fn, x, 2.0) == counted
+
+
 def test_paper_spec_energy_ratio_and_records():
     """End-to-end via the spec layer (``paper_spec`` — the mapping the
     dropped ``train_fl``/``train_sl`` shims used): both pipelines run on

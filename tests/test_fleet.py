@@ -150,9 +150,7 @@ def test_sharded_round_matches_unsharded_host_mesh():
     subprocess because forcing 4 host devices must precede jax init."""
     script = textwrap.dedent("""
         import os
-        os.environ["XLA_FLAGS"] = (
-            "--xla_force_host_platform_device_count=4 "
-            "--xla_cpu_use_thunk_runtime=false")
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import json
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding
@@ -161,7 +159,7 @@ def test_sharded_round_matches_unsharded_host_mesh():
         from repro.fleet.engine import (FLEET_EQUIV_ATOL, make_fleet_fl_round,
                                         make_fleet_sl_round,
                                         shard_client_stack,
-                                        shard_server_state)
+                                        shard_fleet_sl_state)
         from repro.launch.mesh import make_fleet_mesh
         from repro.launch.steps import fleet_server_pspecs
         from repro.models.cnn import CNN_BUILDERS, cross_entropy_loss
@@ -220,10 +218,8 @@ def test_sharded_round_matches_unsharded_host_mesh():
         def sl_state():
             return (stack, sp, init_stacked(opt_c, cp0, C), opt_s.init(sp))
 
-        def sl_sharded_state(m):
-            return (shard_client_stack(stack, m), sp,
-                    shard_client_stack(init_stacked(opt_c, cp0, C), m),
-                    opt_s.init(sp))
+        def sl_sharded_state(m, **kw):
+            return shard_fleet_sl_state(sl_state(), m, **kw)
 
         plain_sl = jax.jit(make_fleet_sl_round(
             step, opt_c, opt_s, local_rounds=S))(*sl_state(), batches)
@@ -262,10 +258,7 @@ def test_sharded_round_matches_unsharded_host_mesh():
         out2d = jax.jit(make_fleet_sl_round(
             step, opt_c, opt_s, local_rounds=S, mesh=mesh2d,
             server_pspecs=sps))(
-                shard_client_stack(stack, mesh2d),
-                shard_server_state(sp, mesh2d, sps),
-                shard_client_stack(init_stacked(opt_c, cp0, C), mesh2d),
-                opt_s.init(shard_server_state(sp, mesh2d, sps)),
+                *sl_sharded_state(mesh2d, server_pspecs=sps),
                 shard_client_stack(batches, mesh2d))
         diffs["sl_2d_loss"] = float(jnp.abs(plain_sl[4] - out2d[4]).max())
         diffs["sl_2d_par"] = max(tree_diff(plain_sl[0], out2d[0]),
@@ -275,7 +268,7 @@ def test_sharded_round_matches_unsharded_host_mesh():
 
         # the same layout through the SPEC layer: EngineSpec(server_mesh=)
         # auto-builds the ('data','fsdp','tp') mesh and plan.init() places
-        # the live server params + Adam moments with shard_server_state
+        # the live server params + Adam moments with shard_fleet_sl_state
         # (incl. the OptState(step=P(), mu=specs, nu=specs) spec tree)
         from repro.api import (ClientSpec, CutPolicy, DataSpec, EngineSpec,
                                ExperimentSpec, ModelSpec, compile_experiment)
@@ -313,6 +306,54 @@ def test_sharded_round_matches_unsharded_host_mesh():
             assert v < rec["atol"], (k, rec)
     # the 2D run's server suffix really lives on the fsdp axis
     assert any("fsdp" in s for s in rec["server_specs_out"]), rec
+
+
+def test_mesh_plan_compiles_round_once():
+    """Fleet plans on a forced 4-device mesh (``data=4`` under both client
+    axes, ``server_mesh=(2, 2)`` under vmap): ``plan.init()`` places the
+    engine state where the round returns it, so the rounds after the first
+    compile nothing (the first round would otherwise run a program of its
+    own, and GSPMD would pick where the server-mesh round leaves the
+    client optimizer state)."""
+    script = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import json
+        from repro.api import (ClientSpec, DataSpec, EngineSpec,
+                               ExperimentSpec, ModelSpec, compile_experiment)
+        from repro.launch.mesh import make_fleet_mesh
+        from repro.obs.gauges import RecompileCounter
+
+        counter = RecompileCounter().install()
+        compiles = {}
+        for name, axis, server_mesh in (("vmap", "vmap", None),
+                                        ("shard_map", "shard_map", None),
+                                        ("vmap_2x2", "vmap", (2, 2))):
+            spec = ExperimentSpec(
+                model=ModelSpec(name="tinycnn", num_classes=4),
+                data=DataSpec(kind="synthetic", image_size=16,
+                              classes_per_client=2),
+                clients=ClientSpec(num_clients=8),
+                engine=EngineSpec(kind="sl", client_axis=axis,
+                                  server_mesh=server_mesh),
+                global_rounds=3, local_steps=2, batch_size=2)
+            plan = compile_experiment(
+                spec, mesh=None if server_mesh else make_fleet_mesh(8))
+            state = plan.init()
+            state, _ = plan.run_round(state, with_eval=False)
+            n0 = counter.snapshot()[0]
+            for _ in range(2):
+                state, _ = plan.run_round(state, with_eval=False)
+            compiles[name] = counter.snapshot()[0] - n0
+        print(json.dumps(compiles))
+    """)
+    env = dict(os.environ, PYTHONPATH=SRC)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    compiles = json.loads(out.stdout.strip().splitlines()[-1])
+    assert compiles == {"vmap": 0, "shard_map": 0, "vmap_2x2": 0}, compiles
 
 
 # ---------------------------------------------------------------------------

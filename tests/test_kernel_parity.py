@@ -124,3 +124,23 @@ def test_kernel_spec_validation():
         compile_experiment(dataclasses.replace(
             CNN_BASE, link_policy=LinkPolicy(compress="none"),
             engine=EngineSpec("sl", "vmap", link_kernel="fused")))
+
+
+def test_lm_bill_excludes_block_recompute():
+    """The split LM recomputes its blocks in the backward pass (a device
+    memory choice), but the energy bill prices the program without that
+    recompute: LM_BASE's client and server step FLOPs, as XLA:CPU counts
+    them, are those of the plain forward + backward."""
+    import jax
+    import jax.numpy as jnp
+    from repro.fleet.hetero import lm_split_program
+
+    prog = lm_split_program(TINY_ARCH, jax.random.PRNGKey(0), 1)
+    tokens = jnp.zeros((4, 16), jnp.int32)
+    text = lambda step: str(jax.make_jaxpr(step.client_fwd)(
+        prog.params_c0, tokens))
+    assert "remat" in text(prog.step)
+    assert "remat" not in text(prog.cost_step)
+    (cut, (fl_client, fl_server, _)), = compile_experiment(
+        LM_BASE).flops.items()
+    assert (cut, fl_client, fl_server) == (1, 4613664.0, 5422432.0)

@@ -102,7 +102,7 @@ def test_fused_quant_dequant_matches_two_op(m):
     """The fused pallas path of quant_dequant must equal its own two-op
     reference (same f32 math, no HBM int8 round-trip)."""
     x = jax.random.normal(jax.random.PRNGKey(m), (m, 128)) * 4.0
-    y_fused = quant_dequant(x, use_pallas=True)
+    y_fused = quant_dequant(x, use_pallas=True, interpret=True)
     y_ref = quant_dequant(x, use_pallas=False)
     np.testing.assert_allclose(np.asarray(y_fused), np.asarray(y_ref),
                                atol=1e-6)
@@ -117,7 +117,8 @@ def test_fused_residual_epilogue(m):
     r = jax.random.normal(kr, (m, 128))
     want = quant_dequant(x) + r
     for use_pallas in (True, False):
-        got = quant_dequant_residual(x, r, use_pallas=use_pallas)
+        got = quant_dequant_residual(x, r, use_pallas=use_pallas,
+                                     interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
 
@@ -264,6 +265,17 @@ def test_dispatch_resolution_cpu():
     with pytest.raises(ValueError):
         resolve_link_kernel("fp8")
     assert "fused" in LINK_KERNELS
+
+
+def test_dispatch_follows_default_device():
+    """``jax.default_device`` names the platform a trace compiles for: a
+    described accelerator gets the compiled kernel, the host CPU the
+    interpreter, whatever the default backend."""
+    from repro.kernels.dispatch import accelerator_backend, pallas_interpret
+    with jax.default_device("tpu"):
+        assert accelerator_backend() and not pallas_interpret()
+    with jax.default_device(jax.devices("cpu")[0]):
+        assert not accelerator_backend() and pallas_interpret()
 
 
 # ---------------------------------------------------------------------------

@@ -118,7 +118,9 @@ def test_mini_dryrun_subprocess(tmp_path):
         import dataclasses
         cfg = dataclasses.replace(
             ARCHS["smollm-135m"].reduced(), vocab=512, d_model=256, d_ff=512)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         import repro.configs.base as base
         shape = base.InputShape("mini", 64, 8, "train")
         import repro.launch.steps as steps

@@ -156,8 +156,8 @@ def test_summarize_round_metrics_is_pure_numpy():
 def _round_jaxpr(plan) -> str:
     """Canonical jaxpr of the plan's jitted round via the same audit handle
     ``repro_lint --jaxpr`` traces."""
-    from repro.analyze.jaxpr_audit import _canon_jaxpr, _example_round_args
-    args, audit = _example_round_args(plan)
+    from repro.analyze.jaxpr_audit import _canon_jaxpr, example_round_args
+    args, audit = example_round_args(plan)
     return _canon_jaxpr(jax.make_jaxpr(audit["jit_fn"])(*args))
 
 

@@ -238,14 +238,20 @@ def test_recompile_counter_fires_on_shape_change():
 
 
 def test_recompile_counter_install_uninstall():
+    from jax._src import monitoring
     c = RecompileCounter()
     c.install()
-    if c.available:
-        n0 = c.snapshot()[0]
-        jax.block_until_ready(jax.jit(lambda x: x + 1)(jnp.zeros(37)))
-        assert c.snapshot()[0] > n0
+    assert c.available
+    n0 = c.snapshot()[0]
+    jax.block_until_ready(jax.jit(lambda x: x + 1)(jnp.zeros(37)))
+    assert c.snapshot()[0] > n0
     c.uninstall()
     assert not c.available
+    # the listener is really gone: a later compile no longer reaches it
+    assert c._listen not in monitoring.get_event_duration_listeners()
+    n1 = c.snapshot()[0]
+    jax.block_until_ready(jax.jit(lambda x: x + 2)(jnp.zeros(41)))
+    assert c.snapshot()[0] == n1
 
 
 # ---------------------------------------------------------------------------
@@ -301,11 +307,22 @@ def test_profiler_capture_window(tmp_path):
     cap.round_started(2)
     cap.round_finished(2)                      # window closes
     cap.close()
-    # capture is best-effort (profiler availability varies by build): the
-    # status line must say what happened either way
-    assert cap.status.startswith(("captured", "unavailable", "stop failed"))
-    if cap.status.startswith("captured"):
-        assert os.path.isdir(str(tmp_path / "prof"))
+    assert cap.status.startswith("captured") and not cap.active
+    assert os.path.isdir(str(tmp_path / "prof"))
+
+
+def test_profiler_failed_start_raises(tmp_path, monkeypatch):
+    """A capture the caller asked for that cannot start fails the run
+    instead of recording a status and carrying on."""
+    import jax.profiler
+
+    def broken(*a, **k):
+        raise RuntimeError("no profiler")
+    monkeypatch.setattr(jax.profiler, "start_trace", broken)
+    cap = ProfilerCapture((0, 0), str(tmp_path / "prof"))
+    with pytest.raises(RuntimeError, match="no profiler"):
+        cap.round_started(0)
+    assert not cap.active
 
 
 def test_profiler_validates_window():
@@ -409,7 +426,7 @@ def test_profile_rounds_capture_via_plan(tmp_path):
     plan.run(rounds=2, with_eval=False)
     plan.obs.close()
     man = json.load(open(os.path.join(plan.obs.run_dir, "manifest.json")))
-    assert man["profiler"].startswith(("captured", "unavailable"))
+    assert man["profiler"].startswith("captured")
 
 
 def test_obs_overhead_under_2pct():
