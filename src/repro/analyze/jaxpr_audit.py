@@ -47,7 +47,6 @@ import re
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
 
 from .findings import Finding, Report
 
@@ -233,15 +232,12 @@ def example_round_args(plan) -> tuple[tuple, dict]:
             "plan's run closure carries no _audit handle; hetero-bucketed "
             "plans dispatch per bucket on the host and have no single "
             "jittable round to audit (same restriction as run_monte_carlo)")
+    from ..api.plan import round_args
     state = plan.init()
     cohort = plan._round_cohort(state)
     batches = plan.round_batches(state, cohort=cohort)
-    es = state.engine_state
-    args = tuple(es) if audit["unpack_state"] else (es,)
-    args += (batches,)
-    if audit["masked"]:
-        args += (jnp.ones(plan.spec.clients.num_clients, jnp.float32),)
-    return args, audit
+    return round_args(audit, state.engine_state, batches, None,
+                      plan.spec.clients.num_clients), audit
 
 
 def audit_plan(plan, *, const_budget_bytes: int = 1 << 20) -> Report:

@@ -36,9 +36,9 @@ from ..core.trajectory import TourPlan, plan_tour
 from ..data.partition import (partition_dirichlet, partition_iid,
                               partition_non_iid, population_partition_count)
 from ..data.synthetic import SyntheticPestImages, synthetic_tokens
-from ..fleet.engine import (fleet_sl_state_shardings, make_fleet_fl_round,
-                            make_fleet_sl_round, server_mesh_sizes,
-                            validate_fleet_mesh)
+from ..fleet.engine import (fleet_sl_state_shardings, jit_round,
+                            make_fleet_fl_round, make_fleet_sl_round,
+                            server_mesh_sizes, validate_fleet_mesh)
 from ..launch.mesh import make_fleet_mesh, single_device_fleet_mesh
 from ..fleet.hetero import (HeteroFleet, assign_cuts_cnn, cnn_split_program,
                             lm_split_program)
@@ -152,6 +152,7 @@ class Plan:
         # plans, which have no single jittable round)
         (self._init_state, self._run, self._eval, self._run_raw,
          self._eval_acc_raw) = engine_fns
+        self._round_ops_written = False
 
     # ---- lifecycle --------------------------------------------------------
 
@@ -277,6 +278,8 @@ class Plan:
                 if batches is None:
                     batches = self.round_batches(state, cohort=cohort)
                 mask = self._round_mask(state, cohort=cohort)
+            if obs and not self._round_ops_written:
+                self._write_round_ops(state.engine_state, batches, mask)
             with obs.span("round/execute", round=r) as sp:
                 out = self._run(state.engine_state, batches, mask)
                 if self.graph_taps:
@@ -304,6 +307,20 @@ class Plan:
         obs.round_finished(r)
         state.round += 1
         return state, rec
+
+    def _write_round_ops(self, engine_state, batches, mask) -> None:
+        """Hand the compiled round's HLO to ``Obs.op_scopes`` (each op's
+        scope, for a profile of the round), lowered from the arguments the
+        first round runs with: its call then reuses this compile."""
+        self._round_ops_written = True
+        audit = getattr(self._run, "_audit", None)
+        if audit is None:       # hetero buckets: no single round program
+            return
+        args = round_args(audit, engine_state, batches, mask,
+                          self.spec.clients.num_clients)
+        with self.obs.span("round/ops"):
+            self.obs.op_scopes(
+                audit["jit_fn"].lower(*args).compile().as_text())
 
     def _assemble_record(self, state: PlanState, losses, mask, cohort, *,
                          with_eval: bool, taps=None) -> RoundRecord:
@@ -927,6 +944,18 @@ def _sl_audit(round_fn, masked: bool) -> dict:
             "unpack_state": True, "masked": masked}
 
 
+def round_args(audit: dict, engine_state, batches, mask, n: int) -> tuple:
+    """The positional arguments a plan's run closure hands its jitted round
+    (``audit`` is the closure's ``_audit`` handle; ``mask`` None on a
+    mask-aware round means every one of the ``n`` clients)."""
+    args = tuple(engine_state) if audit["unpack_state"] else (engine_state,)
+    args += (batches,)
+    if audit["masked"]:
+        args += (jnp.ones(n, jnp.float32) if mask is None
+                 else jnp.asarray(mask),)
+    return args
+
+
 def _mask_runner(round_fn, masked: bool, n: int, audit: dict = None,
                  with_taps: bool = False):
     """Uniform ``run(state, batches, mask)`` closure over a round builder
@@ -970,7 +999,7 @@ def _compile_fl(spec, mesh, stages, params0, x_test_j, y_test, taps=()):
                                      taps=taps)
     else:
         raw_fn = make_fl_round(grad_fn, opt, client_axis="scan", taps=taps)
-    round_fn = jax.jit(raw_fn, donate_argnums=(0,))
+    round_fn = jit_round(raw_fn, "fl_round", donate_argnums=(0,))
 
     def init_state():
         return jax.tree_util.tree_map(jnp.copy, params0)
@@ -1042,7 +1071,7 @@ def _compile_sl_scan(spec, stages, params0, k, link, x_test_j, y_test,
     raw_fn = make_multi_client_round(step, opt_c, opt_s,
                                      local_rounds=spec.local_steps,
                                      taps=taps)
-    round_fn = jax.jit(raw_fn, donate_argnums=(0, 1, 2, 3))
+    round_fn = jit_round(raw_fn, "sl_round", donate_argnums=(0, 1, 2, 3))
 
     def init_state():
         state = (stack_replicas(cp0, n), sp, init_stacked(opt_c, cp0, n),
@@ -1126,7 +1155,8 @@ def _compile_sl_fleet(spec, mesh, stages, params0, cut_of_client, link,
             return jax.tree_util.tree_map(jnp.copy, state)
 
         if mesh is None:
-            round_fn = jax.jit(raw_fn, donate_argnums=(0, 1, 2, 3))
+            round_fn = jit_round(raw_fn, "sl_round",
+                                 donate_argnums=(0, 1, 2, 3))
             init_state = fresh_state
         else:
             # the round returns its state where init_state places it, so
@@ -1134,8 +1164,8 @@ def _compile_sl_fleet(spec, mesh, stages, params0, cut_of_client, link,
             shardings = fleet_sl_state_shardings(
                 jax.eval_shape(fresh_state), mesh, shared=shared,
                 server_pspecs=sps_specs)
-            round_fn = jax.jit(
-                raw_fn, donate_argnums=(0, 1, 2, 3),
+            round_fn = jit_round(
+                raw_fn, "sl_round", donate_argnums=(0, 1, 2, 3),
                 out_shardings=shardings + (None,) * (2 if taps else 1))
 
             def init_state():
@@ -1242,7 +1272,7 @@ def _compile_sl_stack(spec, mesh, prog, x_test_j, y_test, taps=()):
                                      client_axis=spec.engine.client_axis,
                                      client_tier="shared" if shared
                                      else "stacked", taps=taps)
-    round_fn = jax.jit(raw_fn, donate_argnums=(0, 1, 2, 3))
+    round_fn = jit_round(raw_fn, "sl_round", donate_argnums=(0, 1, 2, 3))
 
     def init_state():
         if shared:

@@ -30,6 +30,15 @@ import jax.numpy as jnp
 
 Params = Any
 
+# Named scopes of the compiled round (``jax.named_scope``): every HLO op of
+# a tier, its backward (``transpose(jvp(...))``) and its recompute carries
+# the tier's scope in its ``op_name`` metadata, so a device profile charges
+# each op to the client tier, the link or the server tier.
+CLIENT_SCOPE = "sl/client"
+LINK_SCOPE = "sl/link"
+SERVER_SCOPE = "sl/server"
+FL_SCOPE = "fl/client"
+
 
 # ---------------------------------------------------------------------------
 # 1. stage lists (CNN repro)
@@ -128,18 +137,26 @@ class SplitStep:
     # tap-free trace.
     taps: tuple = ()
 
+    def _link(self, x):
+        if self.link_constraint is None:
+            return x
+        with jax.named_scope(LINK_SCOPE):
+            return self.link_constraint(x)
+
     def loss_fn(self, params_c, params_s, batch):
         inputs, targets = batch["inputs"], batch["targets"]
-        raw_smashed = smashed = self.client_fwd(params_c, inputs)
-        if self.link_constraint is not None:
-            smashed = self.link_constraint(smashed)
+        with jax.named_scope(CLIENT_SCOPE):
+            raw_smashed = self.client_fwd(params_c, inputs)
+        smashed = self._link(raw_smashed)
         if self.variant == "vanilla":
-            loss, aux = self.server_loss(params_s, smashed, targets)
+            with jax.named_scope(SERVER_SCOPE):
+                loss, aux = self.server_loss(params_s, smashed, targets)
         elif self.variant == "ushaped":
-            feats = self.server_body(params_s, smashed)
-            if self.link_constraint is not None:
-                feats = self.link_constraint(feats)
-            loss, aux = self.client_head_loss(params_c, feats, targets)
+            with jax.named_scope(SERVER_SCOPE):
+                feats = self.server_body(params_s, smashed)
+            feats = self._link(feats)
+            with jax.named_scope(CLIENT_SCOPE):
+                loss, aux = self.client_head_loss(params_c, feats, targets)
         else:
             raise ValueError(self.variant)
         aux = dict(aux)
@@ -162,10 +179,12 @@ def make_split_train_step(step: SplitStep, opt_c, opt_s):
 
     def train_step(params_c, params_s, oc, os_, batch):
         loss, aux, g_c, g_s = step.grads(params_c, params_s, batch)
-        up_c, oc = opt_c.update(g_c, oc, params_c)
-        up_s, os_ = opt_s.update(g_s, os_, params_s)
-        params_c = apply_updates(params_c, up_c)
-        params_s = apply_updates(params_s, up_s)
+        with jax.named_scope(CLIENT_SCOPE):
+            up_c, oc = opt_c.update(g_c, oc, params_c)
+            params_c = apply_updates(params_c, up_c)
+        with jax.named_scope(SERVER_SCOPE):
+            up_s, os_ = opt_s.update(g_s, os_, params_s)
+            params_s = apply_updates(params_s, up_s)
         metrics = {"loss": loss, **aux}
         return params_c, params_s, oc, os_, metrics
 
@@ -210,10 +229,12 @@ def make_multi_client_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
         params_s, os_ = carry
         params_c, oc, batch = client_state
         loss, aux, g_c, g_s = step.grads(params_c, params_s, batch)
-        up_c, oc = opt_c.update(g_c, oc, params_c)
-        params_c = apply_updates(params_c, up_c)
-        up_s, os_ = opt_s.update(g_s, os_, params_s)
-        params_s = apply_updates(params_s, up_s)
+        with jax.named_scope(CLIENT_SCOPE):
+            up_c, oc = opt_c.update(g_c, oc, params_c)
+            params_c = apply_updates(params_c, up_c)
+        with jax.named_scope(SERVER_SCOPE):
+            up_s, os_ = opt_s.update(g_s, os_, params_s)
+            params_s = apply_updates(params_s, up_s)
         if taps:
             t = step_taps(taps, loss=loss, aux_taps=aux.get("taps"),
                           g_c=g_c, g_s=g_s, up_c=up_c, up_s=up_s)
@@ -302,9 +323,10 @@ def make_fl_round(grad_fn: Callable, opt, *, client_axis: str = "scan",
 
         def local_step(carry, batch):
             params, opt_state = carry
-            loss, grads = grad_fn(params, batch)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            new_carry = (apply_updates(params, updates), opt_state)
+            with jax.named_scope(FL_SCOPE):
+                loss, grads = grad_fn(params, batch)
+                updates, opt_state = opt.update(grads, opt_state, params)
+                new_carry = (apply_updates(params, updates), opt_state)
             if taps:
                 t = step_taps(taps, loss=loss, g_c=grads, up_c=updates)
                 return new_carry, (loss, t)

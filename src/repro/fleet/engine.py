@@ -67,7 +67,8 @@ from ..core.fedavg import (fedavg_mean, fedavg_mean_masked, fedavg_pmean,
                            fedavg_pmean_masked, fedavg_pmean_stack,
                            fedavg_pmean_stack_masked, fedavg_stack,
                            fedavg_stack_masked)
-from ..core.split import SplitStep, make_fl_round
+from ..core.split import (CLIENT_SCOPE, SERVER_SCOPE, SplitStep,
+                          make_fl_round)
 from ..obs.metrics import tree_nonfinite, tree_norm
 from ..optim.optimizers import OptState, apply_updates
 
@@ -81,6 +82,16 @@ FLEET_EQUIV_ATOL = 1e-3
 CLIENT_AXIS_NAME = "data"
 
 CLIENT_AXES = ("vmap", "shard_map")
+
+
+def jit_round(raw_fn: Callable, name: str, **jit_kw):
+    """``jax.jit`` of a round function under a stable ``name``: the
+    compiled module is ``jit_<name>`` (as the profiler's ``XLA Modules``
+    line reads it), whatever ``raw_fn`` itself is called."""
+    def round_fn(*args):
+        return raw_fn(*args)
+    round_fn.__name__ = round_fn.__qualname__ = name
+    return jax.jit(round_fn, **jit_kw)
 
 
 def fleet_sharding(mesh) -> NamedSharding:
@@ -432,12 +443,13 @@ def make_fleet_sl_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
             else:
                 losses, g_c_stack, g_s_stack = grads_out
                 aux_t = {}
-            up_c, oc_new = jax.vmap(opt_c.update)(
-                g_c_stack, oc_stack, params_c_stack)
-            pc_new = apply_updates(params_c_stack, up_c)
-            if mask is not None:
-                pc_new = masked_rows(pc_new, params_c_stack)
-                oc_new = masked_rows(oc_new, oc_stack)
+            with jax.named_scope(CLIENT_SCOPE):
+                up_c, oc_new = jax.vmap(opt_c.update)(
+                    g_c_stack, oc_stack, params_c_stack)
+                pc_new = apply_updates(params_c_stack, up_c)
+                if mask is not None:
+                    pc_new = masked_rows(pc_new, params_c_stack)
+                    oc_new = masked_rows(oc_new, oc_stack)
             params_c_stack, oc_stack = pc_new, oc_new
             # server: ONE update on the fleet-reduced gradient — under
             # shard_map an explicit in-map lax.pmean/psum over `data`, under
@@ -456,15 +468,17 @@ def make_fleet_sl_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
                 if server_reduce == "mean":
                     s = s / n_active
                 return s.astype(g.dtype)
-            g_s = jax.tree_util.tree_map(reduce_g, g_s_stack)
-            up_s, os_new = opt_s.update(g_s, os_, params_s)
-            ps_new = apply_updates(params_s, up_s)
-            if mask is not None:
-                # zero active clients -> the server also sits the round out
-                ps_new = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(any_active, n, o), ps_new, params_s)
-                os_new = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(any_active, n, o), os_new, os_)
+            with jax.named_scope(SERVER_SCOPE):
+                g_s = jax.tree_util.tree_map(reduce_g, g_s_stack)
+                up_s, os_new = opt_s.update(g_s, os_, params_s)
+                ps_new = apply_updates(params_s, up_s)
+                if mask is not None:
+                    # zero active clients -> the server also sits the round out
+                    ps_new = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(any_active, n, o), ps_new,
+                        params_s)
+                    os_new = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(any_active, n, o), os_new, os_)
             if taps:
                 t = dict(aux_t)
                 if "grad_norm_client" in taps:
@@ -558,17 +572,21 @@ def make_fleet_sl_round(step: SplitStep, opt_c, opt_s, *, local_rounds: int,
                 aux_t = {}
             # the shared client tier updates like the server: one step on
             # the masked cohort-MEAN prefix gradient (EPSL)
-            g_c = jax.tree_util.tree_map(lambda g: reduce_g(g, "mean"),
-                                         g_c_stack)
-            up_c, oc_new = opt_c.update(g_c, oc, params_c)
-            pc_new = apply_updates(params_c, up_c)
-            g_s = jax.tree_util.tree_map(lambda g: reduce_g(g, server_reduce),
-                                         g_s_stack)
-            up_s, os_new = opt_s.update(g_s, os_, params_s)
-            ps_new = apply_updates(params_s, up_s)
-            if mask is not None:
-                pc_new, oc_new = guard(pc_new, params_c), guard(oc_new, oc)
-                ps_new, os_new = guard(ps_new, params_s), guard(os_new, os_)
+            with jax.named_scope(CLIENT_SCOPE):
+                g_c = jax.tree_util.tree_map(lambda g: reduce_g(g, "mean"),
+                                             g_c_stack)
+                up_c, oc_new = opt_c.update(g_c, oc, params_c)
+                pc_new = apply_updates(params_c, up_c)
+                if mask is not None:
+                    pc_new, oc_new = guard(pc_new, params_c), guard(oc_new, oc)
+            with jax.named_scope(SERVER_SCOPE):
+                g_s = jax.tree_util.tree_map(
+                    lambda g: reduce_g(g, server_reduce), g_s_stack)
+                up_s, os_new = opt_s.update(g_s, os_, params_s)
+                ps_new = apply_updates(params_s, up_s)
+                if mask is not None:
+                    ps_new = guard(ps_new, params_s)
+                    os_new = guard(os_new, os_)
             if taps:
                 t = dict(aux_t)
                 if "grad_norm_client" in taps:

@@ -31,7 +31,7 @@ from ..core.energy import HardwareProfile
 from ..core.link import LinkConfig
 from ..core.split import SplitStep, Stage, apply_stages, split_stack
 from ..optim.optimizers import init_stacked
-from .engine import make_fleet_sl_round, validate_fleet_mesh
+from .engine import jit_round, make_fleet_sl_round, validate_fleet_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -381,11 +381,11 @@ class HeteroFleet:
                       else None)
             # donate the bucket's stacked state round-over-round (batches
             # and the dropout mask are fresh each round and not donated)
-            engine = jax.jit(make_fleet_sl_round(
+            engine = jit_round(make_fleet_sl_round(
                 prog.step, opt_c, opt_s, local_rounds=local_rounds,
                 mesh=b_mesh, client_dropout=client_dropout,
                 server_reduce=server_reduce, client_axis=client_axis,
-                server_pspecs=pspecs, taps=self.taps),
+                server_pspecs=pspecs, taps=self.taps), "sl_round",
                 donate_argnums=(0, 1, 2, 3))
             state = (_stack_replicas(prog.params_c0, n), prog.params_s0,
                      init_stacked(opt_c, prog.params_c0, n),

@@ -132,6 +132,8 @@ def _flash_forward(q, k, v, causal, window, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the profiler names the call after this: keep "attention" in it
+        name="flash_attention_fwd",
     )(q, k, v)
     return out[:, :, :s] if s_pad != s else out
 
@@ -165,16 +167,19 @@ def _flash_vjp_fwd(q, k, v, causal, window, block_q, block_k, interpret):
 def _flash_vjp_bwd(causal, window, block_q, block_k, interpret, res, g):
     q, k, v, o = res
     d = q.shape[-1]
-    qf, kf, vf = (t.astype(jnp.float32) for t in (q, k, v))
-    gf, of = g.astype(jnp.float32), o.astype(jnp.float32)
-    p = _masked_probs(qf, kf, d, causal, window)
-    dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-    dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vf)
-    delta = jnp.sum(gf * of, axis=-1, keepdims=True)       # (B,H,S,1)
-    ds = p * (dp - delta)
-    dq = jnp.einsum("bhqk,bhkd->bhqd", ds, kf) / math.sqrt(d)
-    dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf) / math.sqrt(d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    # every op of the backward carries the scope ``flash_bwd`` in its
+    # op_name metadata, so a profile charges it to this function
+    with jax.named_scope("flash_bwd"):
+        qf, kf, vf = (t.astype(jnp.float32) for t in (q, k, v))
+        gf, of = g.astype(jnp.float32), o.astype(jnp.float32)
+        p = _masked_probs(qf, kf, d, causal, window)
+        dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
+        dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vf)
+        delta = jnp.sum(gf * of, axis=-1, keepdims=True)   # (B,H,S,1)
+        ds = p * (dp - delta)
+        dq = jnp.einsum("bhqk,bhkd->bhqd", ds, kf) / math.sqrt(d)
+        dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf) / math.sqrt(d)
+        return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

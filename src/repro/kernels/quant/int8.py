@@ -80,6 +80,7 @@ def quantize_int8(x: jax.Array, *, block_rows: int = 256,
         out_shape=[jax.ShapeDtypeStruct((m_pad, d), jnp.int8),
                    jax.ShapeDtypeStruct((m_pad, 1), jnp.float32)],
         interpret=interpret,
+        name="int8_quantize",
     )(_pad_rows(x, m_pad))
     return (q[:m], s[:m]) if m_pad != m else (q, s)
 
@@ -97,6 +98,7 @@ def dequantize_int8(codes: jax.Array, scales: jax.Array, *,
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, d), out_dtype),
         interpret=interpret,
+        name="int8_dequantize",
     )(_pad_rows(codes, m_pad), _pad_rows(scales, m_pad))
     return y[:m] if m_pad != m else y
 
@@ -114,9 +116,11 @@ def quant_dequant_int8(x: jax.Array, *, residual: jax.Array | None = None,
     if residual is None:
         kernel, in_specs = _quant_dequant_kernel, [spec]
         operands = (_pad_rows(x, m_pad),)
+        name = "int8_quant_dequant"
     else:
         kernel, in_specs = _quant_dequant_residual_kernel, [spec, spec]
         operands = (_pad_rows(x, m_pad), _pad_rows(residual, m_pad))
+        name = "int8_quant_dequant_residual"
     y = pl.pallas_call(
         kernel,
         grid=(m_pad // bm,),
@@ -124,5 +128,6 @@ def quant_dequant_int8(x: jax.Array, *, residual: jax.Array | None = None,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, d), out_dtype),
         interpret=interpret,
+        name=name,
     )(*operands)
     return y[:m] if m_pad != m else y

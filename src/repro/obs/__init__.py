@@ -7,6 +7,7 @@ hot-path touch is a branch plus a no-op call. Enabled, one run writes
     results/runs/<run_id>/
       manifest.json     # spec describe(), jax/backend, mesh, git commit
       events.jsonl      # spans, gauges, records, mission spans, notes
+      ops/<module>.json # the compiled round's op -> named-scope map
       profile/          # optional jax.profiler trace (profile_rounds=)
 
 through four pieces (each its own module):
@@ -16,7 +17,9 @@ through four pieces (each its own module):
 * ``gauges``    — recompile counter (jax monitoring events), engine-state
   pytree bytes (the PR-6 O(cohort) pin), host RSS;
 * ``sink``      — buffered JSONL event stream + merged run manifest;
-* ``profiler``  — opt-in ``jax.profiler`` capture scoped to rounds N..M.
+* ``profiler``  — opt-in ``jax.profiler`` capture scoped to rounds N..M,
+  and the op -> scope map that charges a TPU trace's ops to the program's
+  named scopes (the trace carries no op metadata).
 
 Render a run with ``tools/obs_report.py <run_dir>``; cross-link run dirs
 with the perf trend log via ``benchmarks/report.py --runs``.
@@ -38,7 +41,7 @@ from typing import Optional, Tuple
 
 from .gauges import global_counter, host_rss_bytes, pytree_bytes
 from .metrics import MetricsConfig, NonfiniteError  # noqa: F401 (re-export)
-from .profiler import ProfilerCapture
+from .profiler import ProfilerCapture, hlo_op_scopes
 from .sink import JsonlSink, NullSink, json_default, new_run_id
 from .timeline import (NULL_SPAN, Timeline, fenced,  # noqa: F401 (re-export)
                        time_fenced)
@@ -186,6 +189,20 @@ class Obs:
         if self._counter is None or not self._counter.available:
             return 0
         return self._counter.snapshot()[0] - self._compiles0
+
+    def op_scopes(self, hlo_text: str) -> None:
+        """Write ``ops/<module>.json``, ``{"module", "ops": {instruction:
+        op_name}}``, from a compiled module's HLO text: what a profile
+        needs to charge each device op to the program's named scopes."""
+        if not self.enabled:
+            return
+        import json
+        import os
+        module, ops = hlo_op_scopes(hlo_text)
+        out = os.path.join(self.run_dir, "ops")
+        os.makedirs(out, exist_ok=True)
+        with open(os.path.join(out, f"{module}.json"), "w") as f:
+            json.dump({"module": module, "ops": ops}, f)
 
     def manifest(self, **fields) -> None:
         """Merge fields into ``manifest.json`` (``plan=`` appends to the

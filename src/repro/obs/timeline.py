@@ -22,6 +22,13 @@ Spans nest lexically: the timeline keeps a stack, and every event records
 its full ``path`` ("run/round/execute") plus ``depth``, so a reader can
 rebuild the tree without matching ids. Disabled timelines hand out one
 shared null span — entering it is a branch and two no-op calls.
+
+An enabled span is also a host annotation of the profiler's trace
+(``jax.profiler.TraceAnnotation``, named by the span, with ``round=`` where
+the span has it; the ``round`` span is a ``StepTraceAnnotation``, so the
+trace gets its steps). A profile (``ObsConfig(profile_rounds=...)``) thus
+shows the program's spans on the device trace's clock. With no profiler
+recording, the annotation costs a check and two calls.
 """
 from __future__ import annotations
 
@@ -82,6 +89,17 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str, fields: dict):
+    """The profiler annotation of one span (see the module docstring)."""
+    import jax.profiler
+    r = fields.get("round")
+    if r is None:
+        return jax.profiler.TraceAnnotation(name)
+    if name == "round":
+        return jax.profiler.StepTraceAnnotation(name, step_num=r, round=r)
+    return jax.profiler.TraceAnnotation(name, round=r)
+
+
 class Span:
     """One live phase. Use as a context manager via ``Timeline.span``.
 
@@ -91,7 +109,7 @@ class Span:
     yields the path ``.../round/execute``, not ``.../round/round/execute``.
     """
     __slots__ = ("_tl", "name", "fields", "t_start", "sync_s", "_extra",
-                 "_pushed", "_depth")
+                 "_pushed", "_depth", "_ann")
 
     def __init__(self, tl: "Timeline", name: str, fields: dict):
         self._tl = tl
@@ -116,6 +134,8 @@ class Span:
         stack.extend(segs[k:])
         self._depth = tl._open
         tl._open += 1
+        self._ann = _annotation(self.name, self.fields)
+        self._ann.__enter__()
         self.t_start = time.perf_counter()
         return self
 
@@ -135,6 +155,7 @@ class Span:
 
     def __exit__(self, *exc):
         t_end = time.perf_counter()
+        self._ann.__exit__(*exc)
         tl = self._tl
         stack = tl._stack
         path = "/".join(stack)

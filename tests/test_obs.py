@@ -3,8 +3,10 @@
 The contract under test:
 
   * disabled telemetry is genuinely free-ish: ``obs=None`` and a disabled
-    ``ObsConfig`` share the no-op code path (shared null span, no files),
-    and the disabled path adds < 2% to a measured 20-round run;
+    ``ObsConfig`` share the no-op code path (shared null span, no files,
+    no span, annotation or event over a 20-round run);
+  * enabled spans are profiler annotations, and the compiled round's
+    op -> scope map lands in the run dir;
   * spans nest, fence device work into ``sync_s``, and emit clean
     hierarchical paths (no duplicated segments);
   * the JSONL sink buffers, the manifest merges, ``plan``/``sweep``
@@ -325,6 +327,40 @@ def test_profiler_failed_start_raises(tmp_path, monkeypatch):
     assert not cap.active
 
 
+HLO = """HloModule jit_sl_round, is_scheduled=true
+
+%fused_computation (param_0: f32[4]) -> f32[4] {
+  %param_0 = f32[4]{0} parameter(0)
+  ROOT %exp.1 = f32[4]{0} exponential(%param_0), metadata={op_name="jit(sl_round)/sl/server/exp"}
+}
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.9 = f32[] add(%a, %b), metadata={op_name="reduce_sum"}
+}
+
+ENTRY %main.7 (x: f32[4]) -> f32[] {
+  %x = f32[4]{0} parameter(0), metadata={op_name="x"}
+  %fusion = f32[4]{0} fusion(%x), kind=kLoop, calls=%fused_computation
+  %copy.2 = f32[4]{0} copy(%fusion)
+  %c = f32[] constant(0)
+  ROOT %reduce.3 = f32[] reduce(%copy.2, %c), dimensions={0}, to_apply=%region_0.1, metadata={op_name="jit(sl_round)/sl/client/transpose(jvp(sl/client))/reduce_sum"}
+}
+"""
+
+
+def test_hlo_op_scopes_reads_each_op_of_its_own():
+    from repro.obs.profiler import hlo_op_scopes
+    module, ops = hlo_op_scopes(HLO)
+    assert module == "jit_sl_round"
+    # fusion bodies and reducers run as no op of their own; a fusion with
+    # no metadata is its root's; an op with none is left out
+    assert ops == {"x": "x", "fusion": "jit(sl_round)/sl/server/exp",
+                   "reduce.3": "jit(sl_round)/sl/client/"
+                               "transpose(jvp(sl/client))/reduce_sum"}
+
+
 def test_profiler_validates_window():
     with pytest.raises(ValueError):
         ProfilerCapture((3, 1), "x")
@@ -356,7 +392,14 @@ def mission_run(tmp_path_factory):
 
 def test_plan_run_writes_run_dir(mission_run):
     plan, records, run_dir = mission_run
-    assert sorted(os.listdir(run_dir)) == ["events.jsonl", "manifest.json"]
+    assert sorted(os.listdir(run_dir)) == ["events.jsonl", "manifest.json",
+                                           "ops"]
+    # the compiled round's op -> scope map, under the round's stable name
+    assert os.listdir(os.path.join(run_dir, "ops")) == ["jit_sl_round.json"]
+    ops = json.load(open(os.path.join(run_dir, "ops", "jit_sl_round.json")))
+    assert ops["module"] == "jit_sl_round"
+    for scope in ("sl/client", "sl/server"):
+        assert any(scope in v for v in ops["ops"].values())
     man = json.load(open(os.path.join(run_dir, "manifest.json")))
     assert man["backend"] == jax.default_backend()
     assert len(man["plans"]) == 1
@@ -429,10 +472,86 @@ def test_profile_rounds_capture_via_plan(tmp_path):
     assert man["profiler"].startswith("captured")
 
 
-def test_obs_overhead_under_2pct():
-    """The disabled-telemetry hot path (shared NULL_OBS vs a per-plan
-    disabled Obs — both pay one branch + no-op span per seam) stays
-    within 2% on a measured 20-round run (satellite 6)."""
+def _recording_annotations(monkeypatch, entered):
+    """Stand-ins for ``jax.profiler``'s annotations that record each
+    enter as ``(kind, name, kwargs)`` and each exit as ``("exit", name)``."""
+    def fake(kind):
+        class Annotation:
+            def __init__(self, name, **kwargs):
+                self.name, self.kwargs = name, kwargs
+
+            def __enter__(self):
+                entered.append((kind, self.name, self.kwargs))
+                return self
+
+            def __exit__(self, *exc):
+                entered.append(("exit", self.name))
+                return False
+        return Annotation
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", fake("trace"))
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", fake("step"))
+
+
+def test_enabled_timeline_annotates_each_span(monkeypatch):
+    """An enabled span is one profiler annotation named by the span, with
+    its round (the ``round`` span a step annotation); a disabled timeline
+    enters none."""
+    entered = []
+    _recording_annotations(monkeypatch, entered)
+    tl = Timeline(ListSink(), enabled=True)
+    with tl.span("round", round=3):
+        with tl.span("round/execute", round=3):
+            pass
+    with tl.span("compile/flops"):
+        pass
+    assert entered == [
+        ("step", "round", {"step_num": 3, "round": 3}),
+        ("trace", "round/execute", {"round": 3}),
+        ("exit", "round/execute"), ("exit", "round"),
+        ("trace", "compile/flops", {}), ("exit", "compile/flops")]
+    assert len(tl._sink.events) == 3
+    entered.clear()
+    off = Timeline(ListSink(), enabled=False)
+    with off.span("round", round=0):
+        with off.span("round/execute", round=0):
+            pass
+    assert entered == [] and off._sink.events == []
+
+
+def test_profile_shows_program_spans_on_its_clock(tmp_path):
+    """``profile_rounds`` traces the program's own spans: each traced
+    round's ``round`` step and its ``round/execute`` inside it, named by
+    the span and carrying the round."""
+    import glob
+    plan = compile_experiment(
+        BASE, obs=ObsConfig(run_root=str(tmp_path), run_id="ann",
+                            profile_rounds=(1, 2)))
+    plan.run(rounds=3, with_eval=False)
+    plan.obs.close()
+    path, = glob.glob(os.path.join(plan.obs.run_dir, "profile", "plugins",
+                                   "profile", "*", "*.xplane.pb"))
+    events = [e for p in jax.profiler.ProfileData.from_file(path).planes
+              if p.name.startswith("/host") for ln in p.lines
+              for e in ln.events]
+    rounds = [e for e in events if e.name == "round"]
+    execs = [e for e in events if e.name == "round/execute"]
+    assert sorted(dict(e.stats)["step_num"] for e in rounds) == [1, 2]
+    assert sorted(dict(e.stats)["round"] for e in execs) == [1, 2]
+    for r in rounds:
+        ex, = [e for e in execs
+               if dict(e.stats)["round"] == dict(r.stats)["step_num"]]
+        assert r.start_ns <= ex.start_ns and ex.end_ns <= r.end_ns
+
+
+def test_obs_overhead_under_2pct(monkeypatch):
+    """The disabled-telemetry hot path does no telemetry work: over a
+    20-round run of a plan compiled with ``obs=None`` (the shared
+    NULL_OBS) and of one with a disabled ``ObsConfig``, every seam is
+    handed NULL_SPAN, and no Span is constructed, no profiler annotation
+    entered, no event emitted and no op map written. What that path costs
+    in time is measured on the chip, where the benchmark runs with
+    telemetry off."""
+    from repro.obs.timeline import Span
     spec = ExperimentSpec(
         model=BASE.model, data=BASE.data, clients=BASE.clients,
         cut_policy=BASE.cut_policy, engine=BASE.engine,
@@ -440,26 +559,35 @@ def test_obs_overhead_under_2pct():
     plan_none = compile_experiment(spec)                 # obs=None -> NULL_OBS
     plan_off = compile_experiment(spec, obs=ObsConfig(enabled=False))
     assert plan_none.obs is NULL_OBS and not plan_off.obs
-
     batches = plan_none.round_batches(plan_none.init())
 
-    def trial(plan):
-        st = plan.init()
-        _, wall = fenced(lambda: [
-            plan.run_round(st, batches, with_eval=False)
-            for _ in range(20)])
-        return wall
+    work = {"span": 0, "emit": 0, "op_map": 0}
+    entered, handed = [], []
 
-    for plan in (plan_none, plan_off):                   # warmup / compile
-        trial(plan)
-    # interleave A/B trials so machine-load drift hits both arms equally;
-    # min-of-N is the standard low-noise wall estimator
-    best = {"none": float("inf"), "off": float("inf")}
-    for _ in range(8):
-        best["none"] = min(best["none"], trial(plan_none))
-        best["off"] = min(best["off"], trial(plan_off))
-    ratio = max(best.values()) / min(best.values())
-    assert ratio < 1.02, f"disabled-telemetry overhead {ratio:.4f}x"
+    def count(key, fn):
+        def wrapped(*a, **k):
+            work[key] += 1
+            return fn(*a, **k)
+        return wrapped
+    _recording_annotations(monkeypatch, entered)
+    monkeypatch.setattr(Span, "__init__", count("span", Span.__init__))
+    for sink in (NullSink, JsonlSink):
+        monkeypatch.setattr(sink, "emit", count("emit", sink.emit))
+    monkeypatch.setattr(Obs, "op_scopes", count("op_map", Obs.op_scopes))
+    span = Timeline.span
+    monkeypatch.setattr(Timeline, "span", lambda self, *a, **k: (
+        handed.append(span(self, *a, **k)) or handed[-1]))
+
+    for plan in (plan_none, plan_off):
+        st = plan.init()
+        for _ in range(20):
+            st, rec = plan.run_round(st, batches, with_eval=False)
+        assert st.round == 20 and np.isfinite(rec.loss)
+    assert work == {"span": 0, "emit": 0, "op_map": 0} and entered == []
+    # round, round/sample, round/execute, round/account: each round, each
+    # plan
+    assert len(handed) == 2 * 20 * 4
+    assert all(sp is NULL_SPAN for sp in handed)
 
 
 # ---------------------------------------------------------------------------

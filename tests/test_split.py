@@ -178,3 +178,45 @@ def test_moe_cut_clamped_to_first_moe_layer():
     cfg = ARCHS["deepseek-moe-16b"]
     cut = default_cut_layer(cfg, 0.75)  # would be layer 21 without clamp
     assert cut == 1                      # clamped: experts are server-side
+
+
+def test_compiled_round_carries_tier_and_kernel_scopes():
+    """Every op of a compiled split round names its tier in its op_name
+    metadata (``sl/client``, ``sl/server``; backward and update included),
+    and the flash-attention backward names itself (``flash_bwd``): what a
+    device profile is charged by."""
+    from repro.fleet.engine import jit_round, make_fleet_sl_round
+    from repro.kernels.attn.flash import flash_attention
+    from repro.obs.profiler import hlo_op_scopes
+    from repro.optim import adamw, init_stacked
+
+    clients, steps, b, s, d = 2, 2, 2, 16, 8
+
+    def client_fwd(pc, x):
+        return jnp.tanh(x @ pc["w"])
+
+    def server_loss(ps, h, y):
+        q = (h @ ps["w"])[:, None]                        # (B, 1, S, d)
+        o = flash_attention(q, q, q, block_q=8, block_k=8, interpret=True)
+        return jnp.mean((o[:, 0] - y) ** 2), {}
+
+    step = SplitStep(client_fwd=client_fwd, server_loss=server_loss)
+    opt = adamw(1e-3)
+    key = jax.random.PRNGKey(0)
+    pc = {"w": jax.random.normal(key, (d, d))}
+    ps = {"w": jax.random.normal(jax.random.fold_in(key, 1), (d, d))}
+    pcs = jax.tree_util.tree_map(lambda x: jnp.stack([x] * clients), pc)
+    x = jax.random.normal(jax.random.fold_in(key, 2), (clients, steps, b, s, d))
+    batches = {"inputs": x, "targets": x}
+    round_fn = jit_round(make_fleet_sl_round(step, opt, opt,
+                                             local_rounds=steps), "sl_round")
+    text = round_fn.lower(pcs, ps, init_stacked(opt, pc, clients),
+                          opt.init(ps), batches).compile().as_text()
+    for scope in ("sl/client", "sl/server", "flash_bwd"):
+        assert f'op_name="jit(sl_round)/' in text and scope in text, scope
+    module, ops = hlo_op_scopes(text)
+    assert module == "jit_sl_round"
+    # the client's backward keeps its tier's scope; the attention backward
+    # nests in the tier that runs it
+    assert any("sl/client" in v and "transpose" in v for v in ops.values())
+    assert any("sl/server" in v and "flash_bwd" in v for v in ops.values())

@@ -71,6 +71,26 @@ def test_flash_attention_compiles(one_chip, no_cache, grad):
     assert CUSTOM_CALL in _compiled_text(fn, q, q, q)
 
 
+def test_kernels_keep_their_names(one_chip, no_cache):
+    """The trace names a kernel by its HLO instruction: each kernel's
+    ``name`` reaches it, and in the flash-attention gradient only the
+    forward kernel's instructions hold ``attention``, the pattern by which
+    the benchmark finds it."""
+    import re
+    q = jax.ShapeDtypeStruct((2, 9, 2048, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compiled_text(lambda q, k, v: jax.grad(
+        lambda *a: flash_attention(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v), q, q, q)
+    lines = [ln for ln in text.splitlines()
+             if re.match(r"\s*(ROOT )?%\S*attention\S* = ", ln)]
+    assert lines and all("flash_attention_fwd" in ln and CUSTOM_CALL in ln
+                         for ln in lines)
+    x = jax.ShapeDtypeStruct((509, 576), jnp.float32, sharding=one_chip)
+    assert re.search(r"%\S*int8_quant_dequant\S* = ",
+                     _compiled_text(quant_dequant_int8, x))
+
+
 @pytest.mark.parametrize("shape", [(50176, 24), (509, 576)],
                          ids=["mobilenetv2_cut", "width576"])
 def test_quant_dequant_int8_compiles(one_chip, no_cache, shape):
