@@ -201,7 +201,9 @@ class LMSplitProgram:
     2048 the activations the 30 blocks would save do not fit one 16 GB
     v5e. ``cost_step`` is the same program without that recompute and
     without taps: the work the energy bill prices, since the recompute is
-    a device-memory choice, not work of the paper's client hardware.
+    a device-memory choice, not work of the paper's client hardware. Its
+    attention is the jnp path where the step runs the Pallas kernel: XLA's
+    cost analysis prices a kernel's interpret-mode grid loop as one step.
     """
     step: SplitStep
     cost_step: SplitStep
@@ -239,10 +241,13 @@ def lm_split_program(cfg, key, k: int, *,
                                      jnp.float32)
     block_apply = transformer_block_apply(cfg, window=window,
                                           attn_impl=attn_impl)
+    cost_block_apply = transformer_block_apply(
+        cfg, window=window, attn_impl="xla" if attn_impl == "pallas"
+        else attn_impl)
 
-    def run_blocks(stack, h, remat=False):
+    def run_blocks(stack, h, remat=False, apply=block_apply):
         def body(h, blk):
-            return block_apply(blk, h), None
+            return apply(blk, h), None
         h, _ = jax.lax.scan(jax.checkpoint(body) if remat else body, h,
                             stack)
         return h
@@ -250,12 +255,14 @@ def lm_split_program(cfg, key, k: int, *,
     def server_logits(ps, smashed):
         return run_blocks(ps["blocks"], smashed) @ ps["head"]
 
-    def make_step(remat, **kw):
+    def make_step(remat, apply=block_apply, **kw):
         def client_fwd(pc, tokens):
-            return run_blocks(pc["blocks"], pc["embed"][tokens], remat)
+            return run_blocks(pc["blocks"], pc["embed"][tokens], remat,
+                              apply)
 
         def server_loss(ps, smashed, targets):
-            logits = run_blocks(ps["blocks"], smashed, remat) @ ps["head"]
+            logits = run_blocks(ps["blocks"], smashed, remat,
+                                apply) @ ps["head"]
             logp = jax.nn.log_softmax(logits, axis=-1)
             nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
             return jnp.mean(nll), {}
@@ -265,7 +272,7 @@ def lm_split_program(cfg, key, k: int, *,
 
     return LMSplitProgram(step=make_step(True, link_constraint=link_boundary,
                                          taps=taps),
-                          cost_step=make_step(False),
+                          cost_step=make_step(False, cost_block_apply),
                           params_c0={"embed": embed, "blocks": blocks_c},
                           params_s0={"blocks": blocks_s, "head": head},
                           cut_index=k, server_logits=server_logits)

@@ -5,8 +5,9 @@ shape/dtype/causal/sliding-window/GQA plus deterministic parametrized
 sweeps (the container image has no hypothesis — those tests skip locally
 and run in CI's ``.[dev]`` install; the parametrized rows keep coverage
 either way). Gradient parity goes through ``jax.grad`` on BOTH sides:
-``flash_attention`` differentiates via its closed-form custom_vjp — a
-genuinely distinct computation path from jax's autodiff of the oracle.
+``flash_attention`` differentiates via its custom_vjp (the tiled backward
+kernels) — a genuinely distinct computation path from jax's autodiff of
+the oracle.
 Non-block-aligned and degenerate shapes (seq < block, prime M) pin the
 pad-to-block handling that replaced the old shrink-toward-1 fallback.
 """
@@ -198,12 +199,17 @@ def test_flash_matches_ref_param(shape, causal, window):
     (257, 64, False, None),   # prime S, bidirectional (padded kv masked)
     (7, 64, True, None),      # degenerate: seq < block (single tile)
     (1, 64, True, None),      # single position
+    # q and kv tiles of different sizes (block_q, block_k): the backward
+    # kernels' grids differ, several tiles are live and several skipped
+    pytest.param(160, (32, 64), True, None, id="160-32x64-True-None"),
+    pytest.param(160, (64, 32), True, 40, id="160-64x32-True-40"),
 ])
 def test_flash_non_aligned_shapes(s, block, causal, window):
     """Padding path: Q rows pad + slice, padded KV positions masked with
     kv_len — never the old shrink-toward-bq=1 fallback."""
+    block_q, block_k = block if isinstance(block, tuple) else (block, block)
     _assert_flash_matches_ref((2, 2, s, 32), causal, window, seed=3,
-                              block_q=block, block_k=block, grad=True)
+                              block_q=block_q, block_k=block_k, grad=True)
 
 
 def test_flash_bf16():
@@ -235,8 +241,8 @@ def test_attention_wrapper_gqa(h, kh):
 
 
 def test_flash_vmaps():
-    """The fleet engines vmap the split step over clients; the pallas call
-    must batch (pallas has a vmap rule)."""
+    """The fleet engines vmap the split step over clients; the pallas calls
+    must batch (pallas has a vmap rule), the backward kernels included."""
     shape = (3, 2, 2, 64, 32)   # (clients, B, H, S, D)
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q, k, v = (jax.random.normal(kk, shape) for kk in ks)
@@ -245,6 +251,13 @@ def test_flash_vmaps():
     out = jax.vmap(f)(q, k, v)
     ref = jax.vmap(lambda q, k, v: flash_attention_ref(q, k, v))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+    def client_grads(fn):
+        loss = lambda q, k, v: (fn(q, k, v) ** 2).sum()
+        return jax.vmap(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    for a, b in zip(client_grads(f), client_grads(flash_attention_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
 
 # ---------------------------------------------------------------------------

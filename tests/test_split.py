@@ -8,6 +8,8 @@ Key invariants:
   3. FedAvg mean semantics
   4. transformer group-cut partition preserves the function
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -220,3 +222,9 @@ def test_compiled_round_carries_tier_and_kernel_scopes():
     # nests in the tier that runs it
     assert any("sl/client" in v and "transpose" in v for v in ops.values())
     assert any("sl/server" in v and "flash_bwd" in v for v in ops.values())
+    # the backward's two kernels run under its scope (compiled, each is a
+    # custom call; here, interpreted, each is a loop of ops)
+    for kernel in ("flash_bwd_dkv", "flash_bwd_dq"):
+        names = [v for v in ops.values() if f"/{kernel}/" in v]
+        assert names and all(re.search(rf"flash_bwd\b.*/{kernel}/", v)
+                             for v in names), kernel

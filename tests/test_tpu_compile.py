@@ -71,6 +71,34 @@ def test_flash_attention_compiles(one_chip, no_cache, grad):
     assert CUSTOM_CALL in _compiled_text(fn, q, q, q)
 
 
+@pytest.mark.parametrize("dtype,clients", [(jnp.bfloat16, None),
+                                            (jnp.float32, None),
+                                            (jnp.float32, 2)],
+                         ids=["bf16", "f32", "f32_vmap2"])
+def test_flash_backward_is_tiled(one_chip, no_cache, dtype, clients):
+    """SmolLM-135M heads at seq 2048, in bf16 and in f32 as the split-LM
+    cell runs them (vmapped over its two clients): the gradient runs the
+    two backward kernels under the ``flash_bwd`` scope and holds no
+    S x S tensor."""
+    import re
+    shape = (2, 9, 2048, 64) if clients is None else (clients, 2, 9, 2048, 64)
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grad(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(grad if clients is None else jax.vmap(grad),
+                          q, q, q)
+    for kernel in ("flash_bwd_dkv", "flash_bwd_dq"):
+        calls = [ln for ln in text.splitlines()
+                 if re.match(rf"\s*(ROOT )?%\S*{kernel}\S* = ", ln)]
+        assert calls and all(CUSTOM_CALL in ln for ln in calls), kernel
+        assert all(re.search(rf'op_name="[^"]*flash_bwd\b[^"]*/{kernel}/',
+                             ln) for ln in calls), kernel
+    assert "2048,2048]" not in text
+
+
 def test_kernels_keep_their_names(one_chip, no_cache):
     """The trace names a kernel by its HLO instruction: each kernel's
     ``name`` reaches it, and in the flash-attention gradient only the
