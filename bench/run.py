@@ -18,6 +18,11 @@ The cell is an entry of ``BENCHMARK.json``: a configuration
 4. frees the program, runs the plain reference (``bench/reference/``)
    over the same rows from the same seed, and compares (``bench/check.py``).
 
+What depends on the model is found by the configuration's ``family``, one
+file per family and kind in the cell's bench dir: ``jobs/<family>.py``
+(the data and the ``ExperimentSpec``), ``reference/<family>.py`` (the
+plain reference) and ``flops/<family>.py`` (the model FLOPs).
+
 Every metric is read by a reader, ``bench/metrics/<name>.py`` (or, for a
 name split by the metric it moves, such as ``mfu.tokens``, the
 quantity's ``bench/metrics/mfu.py``), from what the run recorded. The last line of standard output is one JSON object
@@ -36,7 +41,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -97,13 +101,6 @@ class Context:
         return [1e3 * r["dur_s"] for r in self.rounds]
 
     # ---- program spans ----
-    def span_durs(self, name: str) -> list:
-        return [e["dur_s"] for e in self.spans if e.get("name") == name]
-
-    def span_mean_ms(self, name: str) -> Optional[float]:
-        d = self.span_durs(name)
-        return 1e3 * sum(d) / len(d) if d else None
-
     def host_ms(self) -> Optional[float]:
         """Mean per round of the round span less its fenced device wait."""
         rounds = {e["round"]: e["dur_s"] for e in self.spans
@@ -156,12 +153,9 @@ def reader_path(name: str, bench_dir: str = BENCH) -> str:
 
 
 def load_reader(name: str, bench_dir: str = BENCH) -> Callable:
-    path = reader_path(name, bench_dir)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return wl.load_module(
+        "bench_metric_" + name.replace(".", "_").replace("-", "_"),
+        reader_path(name, bench_dir)).read
 
 
 def cell_metrics(bench: dict, workload: str, trace: bool) -> list:
@@ -252,7 +246,7 @@ def reference_reading(cell, seed: int, data, *, precision: str = "highest",
                       batch_part: float = 1.0) -> dict:
     """The plain reference over the same first rounds: the rows the batch
     stream names for them (the first ``batch_part`` of each batch)."""
-    ref_mod = importlib.import_module(f"bench.reference.{cell.family}")
+    ref_mod = wl.family_module(cell, "reference")
     stream = wl.BatchStream(cell, data[1], seed)
     rows = [stream.next_round() for _ in range(FIRST_ROUNDS)]
     keep = max(1, int(round(cell.batch * batch_part)))

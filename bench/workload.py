@@ -2,10 +2,12 @@
 
 This is the benchmark's one general generator: every cell is a pair of
 data files (``configs/<config>.json``, ``traffic/<traffic>.json``) that it
-reads, and nothing here names a cell. It makes the cell's data from the
-seed, lowers the job to the program's ``ExperimentSpec``, and reads the
-program's engine state into named views (parameters, Adam first moments)
-that the comparison with the plain reference pairs leaf by leaf.
+reads, and nothing here names a cell or a model family. The family's
+module in ``jobs/`` makes the cell's data from the seed and lowers the
+configuration to the program's ``ExperimentSpec`` around ``job_spec``,
+the fields the traffic sets; this module reads the program's engine state
+into named views (parameters, Adam first moments) that the comparison
+with the plain reference pairs leaf by leaf.
 
 The sampling rules (client partition, per-round batch draw) are written out
 here from the program's documented semantics, so the reference can follow
@@ -14,6 +16,8 @@ the same rows without taking anything the program made.
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import importlib.util
 import json
 import os
 import sys
@@ -95,39 +99,48 @@ def seed32(seed: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# data, made on the device in one jitted call from the seed
+# what depends on the family: one module per family and kind
 # ---------------------------------------------------------------------------
 
+def load_module(name: str, path: str):
+    """The Python file ``path`` as a fresh module named ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family_module(cell: Cell, kind: str):
+    """The cell's family's module of ``kind`` (``jobs``, ``reference``,
+    ``flops``): ``<kind>/<family>.py`` in the cell's bench dir, else in this
+    harness's own. One found elsewhere than here is loaded from its file
+    under the same package name, ``bench.<kind>.<family>``, so that its
+    relative imports (``from . import common``) resolve to this harness."""
+    dirs = list(dict.fromkeys(os.path.realpath(d)
+                              for d in (cell.bench_dir, BENCH)))
+    name = f"bench.{kind}.{cell.family}"
+    for d in dirs:
+        path = os.path.join(d, kind, f"{cell.family}.py")
+        if os.path.isfile(path):
+            return (importlib.import_module(name)
+                    if d == os.path.realpath(BENCH) else load_module(name, path))
+    raise ModuleNotFoundError(
+        f"no {kind} module for family {cell.family!r} of workload "
+        f"{cell.name!r}: looked for {kind}/{cell.family}.py in "
+        + " and ".join(dirs))
+
+
 def make_data(cell: Cell, seed: int):
-    """``(x_train, y_train, x_test, y_test)`` as host arrays."""
-    import jax
-    import jax.numpy as jnp
+    """``(x_train, y_train, x_test, y_test)`` as host arrays, made on the
+    device in one jitted call from the seed by the family's ``jobs``
+    module."""
+    return family_module(cell, "jobs").make_data(cell, seed)
 
-    t = cell.traffic
-    n_tr, n_te = int(t["train_examples"]), int(t["test_examples"])
-    key = jax.random.PRNGKey(seed32(seed))
-    if cell.family == "lm":
-        seq, vocab = cell.seq_len, int(cell.config["vocab_size"])
 
-        @jax.jit
-        def gen(k):
-            return jax.random.randint(k, (n_tr + n_te, seq + 1), 0, vocab,
-                                      jnp.int32)
-        toks = np.asarray(gen(key))
-        return (toks[:n_tr, :-1], toks[:n_tr, 1:],
-                toks[n_tr:, :-1], toks[n_tr:, 1:])
-    size, ch = int(cell.config["image_size"]), int(cell.config["in_channels"])
-    ncls = int(cell.config["num_classes"])
-
-    @jax.jit
-    def gen(k):
-        kx, ky = jax.random.split(k)
-        x = jax.random.normal(kx, (n_tr + n_te, size, size, ch), jnp.float32)
-        y = jax.random.randint(ky, (n_tr + n_te,), 0, ncls, jnp.int32)
-        return x, y
-    x, y = gen(key)
-    x, y = np.asarray(x), np.asarray(y)
-    return x[:n_tr], y[:n_tr], x[n_tr:], y[n_tr:]
+def make_spec(cell: Cell, seed: int):
+    """The cell's job as the program's ``ExperimentSpec``, built by the
+    family's ``jobs`` module around ``job_spec``."""
+    return family_module(cell, "jobs").make_spec(cell, seed)
 
 
 # ---------------------------------------------------------------------------
@@ -182,42 +195,18 @@ def add_program_path(root: str = ROOT) -> None:
         sys.path.insert(0, src)
 
 
-def make_spec(cell: Cell, seed: int):
-    """The cell's job as the program's ``ExperimentSpec``."""
-    from repro.api import (ClientSpec, DataSpec, EngineSpec, ExperimentSpec,
-                           LinkPolicy, ModelSpec)
-    c, t = cell.config, cell.traffic
+def job_spec(cell: Cell, seed: int) -> dict:
+    """The ``ExperimentSpec`` fields that the traffic sets for every family:
+    the clients, the link, the engine, and one round of ``local_steps``
+    steps of ``batch`` rows at ``lr``."""
+    from repro.api import ClientSpec, EngineSpec, LinkPolicy
+    t = cell.traffic
     engine = EngineSpec(kind=t["job"], client_axis=t["client_axis"],
                         link_kernel=t["link_kernel"])
-    link = LinkPolicy(compress=t["link"])
-    common = dict(clients=ClientSpec(num_clients=cell.clients),
-                  link_policy=link, engine=engine, global_rounds=1,
-                  local_steps=cell.steps, batch_size=cell.batch,
-                  lr=float(t["lr"]), seed=seed32(seed))
-    if cell.family == "lm":
-        from repro.configs.base import ArchConfig
-        arch = ArchConfig(
-            name=c["name"], family="dense",
-            n_layers=int(c["num_hidden_layers"]), d_model=int(c["hidden_size"]),
-            n_heads=int(c["num_attention_heads"]),
-            n_kv_heads=int(c["num_key_value_heads"]),
-            head_dim=int(c["head_dim"]), d_ff=int(c["intermediate_size"]),
-            vocab=int(c["vocab_size"]), rope_theta=float(c["rope_theta"]),
-            tie_embeddings=bool(c["tie_word_embeddings"]),
-            dtype=c["block_param_dtype"], source=c["source"])
-        return ExperimentSpec(
-            model=ModelSpec(family="transformer", arch=arch,
-                            attn_impl=c["attn_impl"]),
-            data=DataSpec(kind="tokens", seq_len=cell.seq_len,
-                          partition="iid"), **common)
-    from repro.api import CutPolicy
-    return ExperimentSpec(
-        model=ModelSpec(name=c["program_model"],
-                        num_classes=int(c["num_classes"])),
-        data=DataSpec(kind="arrays", image_size=int(c["image_size"]),
-                      partition=t["partition"],
-                      classes_per_client=int(t.get("classes_per_client", 3))),
-        cut_policy=CutPolicy(fraction=float(c["cut_fraction"])), **common)
+    return dict(clients=ClientSpec(num_clients=cell.clients),
+                link_policy=LinkPolicy(compress=t["link"]), engine=engine,
+                global_rounds=1, local_steps=cell.steps, batch_size=cell.batch,
+                lr=float(t["lr"]), seed=seed32(seed))
 
 
 def make_mesh(cell: Cell):
@@ -246,6 +235,5 @@ def model_flops_per_round(cell: Cell) -> float:
     """Forward and backward FLOPs of one round's training (no recompute),
     plus the forward of the round's eval where the traffic evaluates every
     round, from the family's counter in ``flops/``."""
-    import importlib
-    mod = importlib.import_module(f"bench.flops.{cell.family}")
-    return float(mod.flops_per_round(cell.config, cell.traffic))
+    return float(family_module(cell, "flops").flops_per_round(
+        cell.config, cell.traffic))

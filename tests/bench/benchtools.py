@@ -52,9 +52,12 @@ def load(path):
 
 
 def make_root(path, cells, limits=TINY_LIMITS):
-    """A root with BENCHMARK.json listing ``cells`` (names of TINY) beside
-    the repo's metric readers; each tiny cell reports what the cell it is
-    cut from reports (the CNN ones, cut from cells that are out of
+    """A root with BENCHMARK.json listing ``cells`` beside the repo's metric
+    readers. A cell is a name of TINY, or a pair ``(name, description)``
+    whose description is laid out as TINY's entries: the configuration and
+    traffic files (names in the repo's ``bench/``, or paths), the overrides
+    of each, and the cell whose metrics it reports. Each tiny cell reports
+    what that cell reports (the CNN ones, cut from cells that are out of
     BENCHMARK.json until their comparison holds, report ``setup_s``)."""
     shutil.rmtree(path, ignore_errors=True)
     for d in ("configs", "traffic", "limits"):
@@ -65,8 +68,9 @@ def make_root(path, cells, limits=TINY_LIMITS):
                 os.path.join(path, "bench", "peaks.json"))
     bench = load(os.path.join(ROOT, "BENCHMARK.json"))
     bench["configs"], bench["workloads"] = [], []
-    for name in cells:
-        cfg_file, traffic_file, cfg_kw, traffic_kw, like = TINY[name]
+    for cell in cells:
+        name, desc = (cell, TINY[cell]) if isinstance(cell, str) else cell
+        cfg_file, traffic_file, cfg_kw, traffic_kw, like = desc
         cfg = {**load(os.path.join(BENCH, "configs", cfg_file)), **cfg_kw,
                "name": name + "-config"}
         traffic = {**load(os.path.join(BENCH, "traffic", traffic_file)),
